@@ -1,10 +1,9 @@
 #!/usr/bin/env bash
-# Tier-1 gate: compat status, fast import sweep, monotonic-clock static
-# sweep, then the test suite.  The import sweep catches AxisType-style
-# JAX version breaks in seconds instead of surfacing them as collection
-# errors three minutes in.
+# Tier-1 gate: fast import sweep, monotonic-clock static sweep, then the
+# test suite.  The import sweep catches JAX API breaks in seconds instead
+# of surfacing them as collection errors three minutes in.
 #
-#   scripts/check.sh          full gate: compat + imports + clock sweep
+#   scripts/check.sh          full gate: imports + clock sweep
 #                             + serving perf baseline + tier-1 suite;
 #                             FAILS if any single test exceeds
 #                             REPRO_TEST_TIME_LIMIT seconds (default
@@ -29,14 +28,10 @@ for arg in "$@"; do
     esac
 done
 
-echo "== compat ==" >&2
-python scripts/diagnose.py --compat >&2
-
 echo "== import sweep ==" >&2
 python - <<'PY'
 import importlib
 MODULES = [
-    "repro.compat",
     "repro.configs",
     "repro.core",
     "repro.data",

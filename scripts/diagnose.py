@@ -1,11 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU-only tool: never start the TPU backend (it would hold the chip)
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Hillclimb diagnostics: compile one (arch x shape) and print the top
 ops by bytes / flops / collective bytes (trip-scaled, per chip).
 
   PYTHONPATH=src python scripts/diagnose.py <arch> <shape> [top]
-  PYTHONPATH=src python scripts/diagnose.py --compat   # JAX/shim status
   PYTHONPATH=src python scripts/diagnose.py --spec [verify] [draft] \
       [gamma] [max_len]   # draft/verify speculative compatibility
   PYTHONPATH=src python scripts/diagnose.py --cache [store.npz]
@@ -133,7 +134,7 @@ def quant_report(args: list) -> None:
 
     from repro.configs.registry import ARCH_IDS
     from repro.configs import get_smoke_config
-    from repro.kernels import flash_attention as FA
+    from repro.kernels import ops
     from repro.kernels import ref as R
     from repro.models import layers as L
 
@@ -175,17 +176,17 @@ def quant_report(args: list) -> None:
     pos = jnp.asarray([bs * n_blk - 1, 13], jnp.int32)
     scale = hd ** -0.5
     qd = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
-    out_k = FA.paged_attention(qd, kq, vq, bt, pos, scale=scale,
-                               k_scale=ksc, v_scale=vsc, interpret=True)
+    out_k = ops.paged_attention(qd, kq, vq, bt, pos, scale=scale,
+                                k_scale=ksc, v_scale=vsc)
     out_r = R.paged_attention_ref(qd, kq, vq, bt, pos, scale=scale,
                                   k_scale=ksc, v_scale=vsc)
     err_d = float(jnp.max(jnp.abs(out_k - out_r)))
     qe = jax.random.normal(ks[3], (B, S, H, hd), jnp.float32)
     kn = jax.random.normal(ks[4], (B, S, K, hd), jnp.float32)
     vn = jax.random.normal(ks[0], (B, S, K, hd), jnp.float32)
-    ext_k = FA.paged_extend_attention(qe, kq, vq, kn, vn, bt, pos,
-                                      scale=scale, k_scale=ksc,
-                                      v_scale=vsc, interpret=True)
+    ext_k = ops.paged_extend_attention(qe, kq, vq, kn, vn, bt, pos,
+                                       scale=scale, k_scale=ksc,
+                                       v_scale=vsc)
     ext_r = R.paged_extend_attention_ref(qe, kq, vq, kn, vn, bt, pos,
                                          scale=scale, k_scale=ksc,
                                          v_scale=vsc)
@@ -325,8 +326,6 @@ def trace_report(args: list) -> None:
 
 
 def main():
-    from repro.compat import report
-    print("compat:", json.dumps(report()))
     if "--trace" in sys.argv:
         trace_report([a for a in sys.argv[1:] if not a.startswith("-")])
         return
@@ -342,7 +341,7 @@ def main():
     if "--spec" in sys.argv:
         spec_report([a for a in sys.argv[1:] if not a.startswith("-")])
         return
-    if "--compat" in sys.argv or len(sys.argv) < 3:
+    if len(sys.argv) < 3:
         return
     arch, shape_name = sys.argv[1], sys.argv[2]
     top = int(sys.argv[3]) if len(sys.argv) > 3 else 10

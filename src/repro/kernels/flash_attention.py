@@ -73,16 +73,53 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                             / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
 
 
+def _online_update(q, k, v, mask, m_scr, l_scr, acc_scr, g: int, *,
+                   scale: float, softcap: float):
+    """One online-softmax step of GQA group ``g``: rows ``q`` (R, hd)
+    against keys/values ``k``/``v`` (T, hd) under ``mask`` (R, T).  The
+    group's running (m, l, acc) live at index ``g`` of the scratch."""
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap > 0.0:
+        s = softcap * jnp.tanh(s / softcap)
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_scr[g]                                 # (R, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[g] = acc_scr[g] * alpha + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[g] = m_new
+
+
+def _page_update(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask, m_scr, l_scr,
+                 acc_scr, *, scale: float, softcap: float):
+    """Attend every GQA group to the page resident in ``k_ref``/``v_ref``
+    (1, bs, K, hd): kv-head ``g`` serves query rows ``q_ref[0, g]``.  An
+    int8 page is dequantized here, against its (1, bs, K) scale tiles,
+    right before the dot."""
+    for g in range(k_ref.shape[2]):
+        k = k_ref[0, :, g, :].astype(jnp.float32)     # (bs, hd)
+        v = v_ref[0, :, g, :].astype(jnp.float32)
+        if ks_ref is not None:
+            k = k * ks_ref[0, :, g:g + 1]
+            v = v * vs_ref[0, :, g:g + 1]
+        _online_update(q_ref[0, g].astype(jnp.float32), k, v, mask,
+                       m_scr, l_scr, acc_scr, g, scale=scale, softcap=softcap)
+
+
 def _paged_kernel(*refs, scale: float, softcap: float,
                   bs: int, n_blk: int, quant: bool):
     """Paged-attention decode read: one query token per sequence against
     KV pages selected by the scalar-prefetched block table.
 
-    Grid (B, H, n_blk); the innermost dimension walks the LOGICAL blocks
-    of one sequence while the BlockSpec index_map streams in the
-    PHYSICAL page ``block_tables[b, j]`` — the gather never
-    materialises; unallocated (-1) entries are clipped to page 0 by the
-    index_map and masked here.
+    Grid (B, n_blk); the inner dimension walks the LOGICAL blocks of one
+    sequence while the BlockSpec index_map streams in the whole PHYSICAL
+    page ``block_tables[b, j]`` (all K kv-heads), so each page is read
+    once per sequence and serves all H query heads, group by group.  The
+    gather never materialises; unallocated (-1) entries are clipped to
+    page 0 by the index_map and skipped here.
 
     ``quant=True`` adds per-(page, offset, kv-head) f32 scale tiles
     streamed through the same index_map as the int8 K/V pages; the
@@ -95,8 +132,9 @@ def _paged_kernel(*refs, scale: float, softcap: float,
     else:
         (bt_ref, len_ref, q_ref, k_ref, v_ref,
          o_ref, m_scr, l_scr, acc_scr) = refs
+        ks_ref = vs_ref = None
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -106,34 +144,41 @@ def _paged_kernel(*refs, scale: float, softcap: float,
 
     @pl.when(bt_ref[b, j] >= 0)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)           # (1, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)       # (1, bs)
-        t = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        mask = t < len_ref[b]
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[...][:, 0]                     # (1,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_scr[...][:, 0] * alpha + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
+        t = j * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[2], bs), 1)
+        _page_update(q_ref, k_ref, v_ref, ks_ref, vs_ref, t < len_ref[b],
+                     m_scr, l_scr, acc_scr, scale=scale, softcap=softcap)
 
     @pl.when(j == n_blk - 1)
     def _finalize():
-        l = l_scr[...][:, 0]
-        o_ref[0, ...] = (acc_scr[...]
-                         / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _paged_specs(Kh: int, rows: int, hd: int, bs: int, n_blk: int,
+                 quant: bool):
+    """BlockSpecs shared by the paged decode and extend kernels over a
+    (b, j) grid: the row's (1, K, rows, hd) query tile, whole pages
+    (1, bs, K, hd) looked up through the block table (``j`` past the
+    table is clamped to its last entry), the (1, bs, K) scale tiles of
+    an int8 pool, and (K, rows, ·) online-softmax scratch.  Every block
+    spans whole trailing dimensions, which is what the TPU's (8, 128)
+    tiling rule asks of a block narrower than the tile."""
+    def phys(bt_r, b, j):
+        return jnp.maximum(bt_r[b, jnp.minimum(j, n_blk - 1)], 0)
+
+    row = pl.BlockSpec((1, Kh, rows, hd), lambda b, j, bt_r, s_r: (b, 0, 0, 0))
+    page = pl.BlockSpec((1, bs, Kh, hd),
+                        lambda b, j, bt_r, s_r: (phys(bt_r, b, j), 0, 0, 0))
+    pages = [page, page]
+    if quant:
+        sc = pl.BlockSpec((1, bs, Kh),
+                          lambda b, j, bt_r, s_r: (phys(bt_r, b, j), 0, 0))
+        pages += [sc, sc]
+    scratch = [pltpu.VMEM((Kh, rows, 1), jnp.float32),
+               pltpu.VMEM((Kh, rows, 1), jnp.float32),
+               pltpu.VMEM((Kh, rows, hd), jnp.float32)]
+    return row, pages, scratch
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
@@ -158,61 +203,40 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *,
     n_blk = block_tables.shape[1]
     G = H // Kh
     quant = k_scale is not None
-    qt = q.reshape(B, H, 1, hd)
-    bt = block_tables.astype(jnp.int32)
-    ln = lengths.astype(jnp.int32)
-
-    def page_map(b, h, j, bt_r, ln_r, G=G):
-        return (jnp.maximum(bt_r[b, j], 0), 0, h // G, 0)
-
-    def scale_map(b, h, j, bt_r, ln_r, G=G):
-        return (jnp.maximum(bt_r[b, j], 0), 0, h // G)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, hd),
-                     lambda b, h, j, bt_r, ln_r: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), page_map),
-        pl.BlockSpec((1, bs, 1, hd), page_map),
-    ]
-    operands = [qt, k_pages, v_pages]
+    row, pages, scratch = _paged_specs(Kh, G, hd, bs, n_blk, quant)
+    operands = [q.reshape(B, Kh, G, hd), k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), scale_map),
-                     pl.BlockSpec((1, bs, 1), scale_map)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_blk),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda b, h, j, bt_r, ln_r: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
+        grid=(B, n_blk),
+        in_specs=[row] + pages,
+        out_specs=row,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, softcap=softcap,
                           bs=bs, n_blk=n_blk, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Kh, G, hd), q.dtype),
         interpret=interpret,
-    )(bt, ln, *operands)
-    return out
+    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    return out.reshape(B, H, hd)
 
 
 def _paged_extend_kernel(*refs, scale: float, softcap: float,
                          bs: int, n_blk: int, s_len: int, quant: bool):
     """Fused multi-token extend read: S queries per row walk the row's
     context pages (masked strictly below ``pos`` — the pre-write view),
-    then attend the S-token suffix causally at grid step ``j == n_blk``.
+    then attend the S-token suffix causally at grid step ``j == n_blk``
+    and finalize there.
 
-    The suffix K/V arrives as a dense (B, S, K, hd) operand — on a
-    quantized pool the caller passes the int8 ROUND-TRIP so the scored
-    logits match what later page reads reconstruct.  Finalisation
-    happens in a second ``pl.when`` at the suffix step (pl.when blocks
-    run in body order).
+    Query rows of group ``g`` are laid out ``(G, S)``-major, so row
+    ``r`` sits at suffix position ``r % S``.  The suffix K/V arrives as
+    a dense (B, S, K, hd) operand — on a quantized pool the caller
+    passes the int8 ROUND-TRIP so the scored logits match what later
+    page reads reconstruct.
     """
     if quant:
         (bt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -220,8 +244,10 @@ def _paged_extend_kernel(*refs, scale: float, softcap: float,
     else:
         (bt_ref, pos_ref, q_ref, k_ref, v_ref,
          kn_ref, vn_ref, o_ref, m_scr, l_scr, acc_scr) = refs
+        ks_ref = vs_ref = None
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
+    rows = q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -229,54 +255,20 @@ def _paged_extend_kernel(*refs, scale: float, softcap: float,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _online_update(s, mask, v):
-        m_prev = m_scr[...][:, 0]                     # (S,)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[...] = (l_scr[...][:, 0] * alpha
-                      + jnp.sum(p, axis=1))[:, None]
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new[:, None]
-
     @pl.when((j < n_blk) & (bt_ref[b, jnp.minimum(j, n_blk - 1)] >= 0))
     def _context():
-        q = q_ref[0, 0].astype(jnp.float32)           # (S, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)        # (bs, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if quant:
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)
-        t = j * bs + jax.lax.broadcasted_iota(jnp.int32, (s_len, bs), 1)
-        mask = t < pos_ref[b]
-        s = jnp.where(mask, s, NEG_INF)
-        _online_update(s, mask, v)
+        t = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+        _page_update(q_ref, k_ref, v_ref, ks_ref, vs_ref, t < pos_ref[b],
+                     m_scr, l_scr, acc_scr, scale=scale, softcap=softcap)
 
     @pl.when(j == n_blk)
     def _suffix():
-        q = q_ref[0, 0].astype(jnp.float32)           # (S, hd)
-        k = kn_ref[0, :, 0].astype(jnp.float32)       # (S, hd)
-        v = vn_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap > 0.0:
-            s = softcap * jnp.tanh(s / softcap)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (s_len, s_len), 0)
-        kj = jax.lax.broadcasted_iota(jnp.int32, (s_len, s_len), 1)
-        mask = kj <= qi
-        s = jnp.where(mask, s, NEG_INF)
-        _online_update(s, mask, v)
-
-    @pl.when(j == n_blk)
-    def _finalize():
-        l = l_scr[...][:, 0]
-        o_ref[0, 0, ...] = (acc_scr[...]
-                            / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+        qi = jax.lax.broadcasted_iota(jnp.int32, (rows, s_len), 0) % s_len
+        kj = jax.lax.broadcasted_iota(jnp.int32, (rows, s_len), 1)
+        _page_update(q_ref, kn_ref, vn_ref, None, None, kj <= qi,
+                     m_scr, l_scr, acc_scr, scale=scale, softcap=softcap)
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "softcap", "interpret"))
@@ -301,57 +293,33 @@ def paged_extend_attention(q, k_pages, v_pages, k_new, v_new,
     n_blk = block_tables.shape[1]
     G = H // Kh
     quant = k_scale is not None
-    qt = q.transpose(0, 2, 1, 3)                      # (B, H, S, hd)
-    bt = block_tables.astype(jnp.int32)
-    ps = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-
-    def page_map(b, h, j, bt_r, ps_r, G=G):
-        return (jnp.maximum(bt_r[b, jnp.minimum(j, n_blk - 1)], 0),
-                0, h // G, 0)
-
-    def scale_map(b, h, j, bt_r, ps_r, G=G):
-        return (jnp.maximum(bt_r[b, jnp.minimum(j, n_blk - 1)], 0),
-                0, h // G)
-
-    def new_map(b, h, j, bt_r, ps_r, G=G):
-        return (b, 0, h // G, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, S, hd),
-                     lambda b, h, j, bt_r, ps_r: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), page_map),
-        pl.BlockSpec((1, bs, 1, hd), page_map),
-    ]
-    operands = [qt, k_pages, v_pages]
+    row, pages, scratch = _paged_specs(Kh, G * S, hd, bs, n_blk, quant)
+    suffix = pl.BlockSpec((1, S, Kh, hd),
+                          lambda b, j, bt_r, ps_r: (b, 0, 0, 0))
+    qg = q.reshape(B, S, Kh, G, hd).transpose(0, 2, 3, 1, 4)
+    operands = [qg.reshape(B, Kh, G * S, hd), k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), scale_map),
-                     pl.BlockSpec((1, bs, 1), scale_map)]
         operands += [k_scale, v_scale]
-    in_specs += [pl.BlockSpec((1, S, 1, hd), new_map),
-                 pl.BlockSpec((1, S, 1, hd), new_map)]
     operands += [k_new, v_new]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, H, n_blk + 1),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, S, hd),
-                               lambda b, h, j, bt_r, ps_r: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, 1), jnp.float32),
-            pltpu.VMEM((S, hd), jnp.float32),
-        ],
+        grid=(B, n_blk + 1),
+        in_specs=[row] + pages + [suffix, suffix],
+        out_specs=row,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_paged_extend_kernel, scale=scale,
                           softcap=softcap, bs=bs, n_blk=n_blk, s_len=S,
                           quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Kh, G * S, hd), q.dtype),
         interpret=interpret,
-    )(bt, ps, *operands)
-    return out.transpose(0, 2, 1, 3)
+    )(block_tables.astype(jnp.int32),
+      jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,)), *operands)
+    out = out.reshape(B, Kh, G, S, hd).transpose(0, 3, 1, 2, 4)
+    return out.reshape(B, S, H, hd)
 
 
 def _pick_block(n: int, pref=(512, 256, 128, 64, 32, 16, 8)) -> int:

@@ -1,8 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run in ``interpret=True`` mode — the
-kernel body executes step-by-step in Python, validating BlockSpec
-indexing and the numerics.  On TPU backends they compile for real.
+On the TPU the kernels compile for real.  On the CPU (tests and
+rehearsals) they run in ``interpret=True`` mode — the kernel body
+executes step by step, validating BlockSpec indexing and the numerics.
+Any other backend is an error: silently interpreting there would hide
+the device.
 """
 from __future__ import annotations
 
@@ -15,7 +17,14 @@ from repro.kernels import ssd_scan as _ssd
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels here target the TPU (compiled) or the CPU "
+        f"(interpreted); backend {backend!r} is neither")
 
 
 def quant_matmul(x, wq, scale, out_dtype=jnp.bfloat16):

@@ -18,7 +18,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, h0_ref,
             y_ref, hout_ref, state_scr, *, nc: int, Q: int):
     c_idx = pl.program_id(2)
 
@@ -27,36 +30,42 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,
         state_scr[...] = h0_ref[0, 0].astype(jnp.float32)       # (P, N)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)       # (Q, P)
-    dt = dt_ref[0, 0, 0, 0].astype(jnp.float32)  # (Q,)
-    A = a_ref[0, 0, 0, 0].astype(jnp.float32)    # scalar for this head
+    dt = dt_ref[0, 0, 0]                         # (Q, 1)
+    dA = da_ref[0, 0, 0]                         # (Q, 1) = dt * A
     Bm = b_ref[0, 0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)         # (Q, N)
 
-    dA = dt * A                                  # (Q,)
-    cums = jnp.cumsum(dA)                        # (Q,)
-    xdt = x * dt[:, None]                        # (Q, P)
-
-    # intra-chunk: (C B^T ∘ L) @ (x dt)
-    seg = cums[:, None] - cums[None, :]
+    # in-chunk prefix sums as products with the lower-triangular ones
+    # matrix (Mosaic cannot lower cumsum); full-f32 passes keep the
+    # decay exponents exact to f32 rounding
     tri = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    Lmat = jnp.where(tri, jnp.exp(seg), 0.0)
+    lower = tri.astype(jnp.float32)
+    cums = jnp.dot(lower, dA, precision=_HI,
+                   preferred_element_type=jnp.float32)            # (Q, 1)
+    cums_row = jax.lax.dot_general(dA, lower, (((0,), (1,)), ((), ())),
+                                   precision=_HI,
+                                   preferred_element_type=jnp.float32)
+    total = jnp.sum(dA, axis=0, keepdims=True)                    # (1, 1)
+    xdt = x * dt                                 # (Q, P)
+
+    # intra-chunk: (C B^T ∘ L) @ (x dt)
+    Lmat = jnp.where(tri, jnp.exp(cums - cums_row), 0.0)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     y = jnp.dot(scores * Lmat, xdt, preferred_element_type=jnp.float32)
 
     # inter-chunk: C_t exp(cums_t) . h_prev
     state = state_scr[...]                       # (P, N)
-    Cs = Cm * jnp.exp(cums)[:, None]
-    y += jax.lax.dot_general(Cs, state, (((1,), (1,)), ((), ())),
+    y += jax.lax.dot_general(Cm * jnp.exp(cums), state,
+                             (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
 
     # state update: h <- exp(cums_Q) h + (x dt decay_to_end)^T B
-    decay_end = jnp.exp(cums[Q - 1] - cums)      # (Q,)
-    contrib = jax.lax.dot_general(xdt * decay_end[:, None], Bm,
+    contrib = jax.lax.dot_general(xdt * jnp.exp(total - cums), Bm,
                                   (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    state_scr[...] = state * jnp.exp(cums[Q - 1]) + contrib
+    state_scr[...] = state * jnp.exp(total) + contrib
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
@@ -87,12 +96,13 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, h0=None,
     if h0 is None:
         h0 = jnp.zeros((b, h, p, n), jnp.float32)
 
-    # TPU-friendly layouts
+    # TPU-friendly layouts: dt and dt*A as (Q, 1) column tiles per chunk
     xr = x.transpose(0, 2, 1, 3).reshape(b, h, nc, Q, p)
-    dtr = dt.transpose(0, 2, 1).reshape(b, h, nc, 1, Q)
-    ar = jnp.broadcast_to(A.reshape(1, h), (b, h)).reshape(b, h, 1, 1)
+    dtr = dt.astype(jnp.float32).transpose(0, 2, 1).reshape(b, h, nc, Q, 1)
+    dar = dtr * A.astype(jnp.float32).reshape(1, h, 1, 1, 1)
     br = B.reshape(b, nc, Q, n)
     cr = C.reshape(b, nc, Q, n)
+    col = pl.BlockSpec((1, 1, 1, Q, 1), lambda i, j, c: (i, j, c, 0, 0))
 
     grid = (b, h, nc)
     y, hout = pl.pallas_call(
@@ -100,8 +110,8 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, h0=None,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, p), lambda i, j, c: (i, j, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1, Q), lambda i, j, c: (i, j, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, c: (i, j, 0, 0)),
+            col,
+            col,
             pl.BlockSpec((1, 1, Q, n), lambda i, j, c: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, n), lambda i, j, c: (i, c, 0, 0)),
             pl.BlockSpec((1, 1, p, n), lambda i, j, c: (i, j, 0, 0)),
@@ -116,7 +126,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, h0=None,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(xr, dtr, ar, br, cr, h0)
+    )(xr, dtr, dar, br, cr, h0)
 
     y = y.reshape(b, h, L, p).transpose(0, 2, 1, 3)[:, :l]
     return y, hout

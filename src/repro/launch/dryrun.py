@@ -1,8 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ruff: noqa: E402  — the two lines above MUST precede any jax import:
-# jax locks the device count at first initialization.
+# ruff: noqa: E402  — the lines above MUST precede any jax import: jax
+# locks the device count at first initialization, and a CPU-only tool
+# must never start the TPU backend (it would hold the chip).
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh).
 
 For each combination this proves the distribution config is coherent —

@@ -94,11 +94,10 @@ def analyze(compiled, *, arch: str, shape_name: str, mesh_name: str,
     counts scan bodies once and under-reports by ~num_layers.  We take
     the max of both flops numbers defensively.
     """
-    from repro.compat import cost_analysis
     from repro.launch.hlo_analysis import analyze_hlo
     hlo = compiled.as_text()
     walked = analyze_hlo(hlo)
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = max(float(ca.get("flops", 0.0)), walked.flops)   # per-device
     nbytes = max(float(ca.get("bytes accessed", 0.0)), walked.bytes)
     counts = {k: int(v) for k, v in walked.coll_counts.items()}

@@ -12,7 +12,10 @@ background ``_run`` task; the public API only posts intents to an
 inbox and wakes the loop, so callers never race a step that is
 executing in the default executor.  ``step()`` itself runs via
 ``run_in_executor`` so the event loop stays responsive to arrivals
-during a jitted wave.
+during a jitted wave.  A step that raises stops the loop: every
+outstanding ``StreamHandle.done`` fails with the exception and
+``shutdown()`` re-raises it, so a broken device run ends in an error,
+never in a hang.
 
 CLI:
 
@@ -29,6 +32,8 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
+from pathlib import Path
 from typing import Callable, Optional
 
 import jax
@@ -96,6 +101,7 @@ class AsyncServingFrontend:
         self._wake: Optional[asyncio.Event] = None
         self._task: Optional[asyncio.Task] = None
         self._closing = False
+        self._error: Optional[BaseException] = None
         self.ttft_ms: list[float] = []      # first-token latency/request
         self.itl_ms: list[float] = []       # every inter-token gap
         self.cancel_ms: list[float] = []    # cancel() call -> applied
@@ -120,6 +126,9 @@ class AsyncServingFrontend:
                on_token: Optional[Callable[[Request, int], None]] = None,
                ) -> StreamHandle:
         """Enqueue a request; returns a handle streaming its tokens."""
+        if self._error is not None:
+            raise RuntimeError("frontend stopped on an engine failure") \
+                from self._error
         if self._closing:
             raise RuntimeError("frontend is shutting down")
         h = StreamHandle(req, clock=self._clock)
@@ -142,7 +151,8 @@ class AsyncServingFrontend:
         """Stop the loop and flush the prefix-persist store.
 
         ``drain=True`` finishes in-flight and queued work first;
-        ``drain=False`` cancels everything outstanding.
+        ``drain=False`` cancels everything outstanding.  Re-raises the
+        exception that stopped the step loop, if one did.
         """
         if not drain:
             for uid in list(self._handles):
@@ -150,7 +160,7 @@ class AsyncServingFrontend:
         self._closing = True
         self._wake.set()
         if self._task is not None:
-            await self._task
+            await self._task            # re-raises a failed step
         out = self.engine.close()           # persists hot chains
         if self.trace_path and self.engine.tracer is not None:
             out["trace"] = dict(
@@ -241,7 +251,29 @@ class AsyncServingFrontend:
         if not h.done.done():
             h.done.set_result(h.req)
 
+    def _fail(self, exc: BaseException) -> None:
+        """The step loop died: fail every outstanding handle and
+        pending cancel with ``exc`` so no awaiter waits forever."""
+        self._error = exc
+        for uid in list(self._handles):
+            h = self._handles.pop(uid)
+            self._callbacks.pop(uid, None)
+            h.tokens.put_nowait(None)
+            if not h.done.done():
+                h.done.set_exception(exc)
+        for _, fut, _ in self._cancels:
+            if not fut.done():
+                fut.set_exception(exc)
+        self._cancels = []
+
     async def _run(self) -> None:
+        try:
+            await self._loop()
+        except Exception as e:
+            self._fail(e)
+            raise
+
+    async def _loop(self) -> None:
         loop = asyncio.get_running_loop()
         eng = self.engine
         while True:
@@ -263,6 +295,23 @@ class AsyncServingFrontend:
 
 
 # ---------------------------------------------------------------- CLI
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the
+    cache there and nothing else is set here.  Otherwise the cache goes
+    to ``.jax_cache/`` at the root of the checkout: a fixed path, since
+    a directory that moves between runs never hits.  Entry points call
+    this; importing the module does not.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _build_engine(args):
     cfg = (get_smoke_config(args.arch) if args.scale == "smoke"
            else get_config(args.arch))
@@ -373,6 +422,7 @@ def main() -> None:
                     help="checkpoint from launch.train (else random init)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg, eng = _build_engine(args)
     reqs = _make_requests(cfg, args)
     t0 = default_clock()

@@ -18,8 +18,8 @@ from typing import Any, Optional
 import jax
 import numpy as np
 
-from repro.compat import Mesh, NamedSharding
-from repro.compat import PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 from repro.configs.base import InputShape, ModelConfig
 
 

@@ -234,10 +234,8 @@ Frontend (registry-only; ``launch.serve.AsyncServingFrontend``):
 * ``frontend.steps`` / ``frontend.streams`` / ``frontend.inbox_depth``
   / ``frontend.pending_cancels`` — loop progress and queue depths.
 
-JAX version compatibility: all version-sensitive jax.sharding / mesh
-symbols are imported via ``repro.compat`` (see its module docstring for
-the shim policy); ``scripts/check.sh`` runs an import sweep that
-catches version breaks at import time.
+``scripts/check.sh`` runs an import sweep that catches JAX API breaks at
+import time.
 """
 from repro.serving.engine import (
     EdgeServingEngine,

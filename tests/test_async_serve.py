@@ -178,3 +178,35 @@ def test_shutdown_flushes_persist_store(setup, tmp_path):
     assert st["persist_loaded_chains"] >= 1
     asyncio.run(serve(eng2))
     assert eng2.stats()["prefix_hits"] >= 1     # restart-warm hit
+
+
+def test_failed_step_fails_handles_and_shutdown(setup):
+    """A step that raises (say, a kernel the device refuses) must fail
+    every outstanding handle and re-raise from shutdown() — never leave
+    the caller awaiting a loop that has died."""
+    cfg, params = setup
+    eng = EdgeServingEngine(cfg, params, _scfg())
+    real_step = eng.step
+
+    def step():
+        if eng.steps >= 2:
+            raise RuntimeError("device step failed")
+        return real_step()
+    eng.step = step
+
+    async def run():
+        fe = AsyncServingFrontend(eng)
+        await fe.start()
+        handles = [fe.submit(_req(uid, max_new_tokens=20))
+                   for uid in range(3)]
+        for h in handles:
+            with pytest.raises(RuntimeError, match="device step failed"):
+                await asyncio.wait_for(h.done, timeout=60)
+        with pytest.raises(RuntimeError, match="frontend stopped"):
+            fe.submit(_req(9))
+        with pytest.raises(RuntimeError, match="device step failed"):
+            await asyncio.wait_for(fe.shutdown(), timeout=60)
+        return handles
+
+    handles = asyncio.run(run())
+    assert all(h.done.done() for h in handles)

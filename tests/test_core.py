@@ -2,7 +2,8 @@
 import math
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import trustzones as tz
 from repro.core.network import CHANNEL_CATALOGUE, MultiChannelLink

@@ -294,3 +294,16 @@ def test_paged_ref_matches_contiguous_attention():
                         v.astype(jnp.float32)).reshape(B, H, hd)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-3, atol=2e-3)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Kernels compile on the TPU and interpret on the CPU; any other
+    backend is an error, never a silent fall back to the interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

@@ -8,8 +8,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from repro.compat import PartitionSpec as P
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
+from jax.sharding import PartitionSpec as P
 from repro.configs import ARCH_IDS, INPUT_SHAPES, applicable, get_config, \
     get_smoke_config
 from repro.launch import sharding as sh
@@ -17,8 +17,8 @@ from repro.launch.hlo_analysis import analyze_hlo, parse_module, shape_bytes
 from repro.launch.specs import input_specs
 from repro.models import model as M
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(shape_tree, spec_tree, mesh):
@@ -106,8 +106,7 @@ def test_hlo_analyzer_counts_scan_trip():
     walked = analyze_hlo(compiled.as_text())
     analytic = 2 * 16 * 64 * 64 * 5
     assert walked.flops == pytest.approx(analytic, rel=0.05)
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     assert ca["flops"] < walked.flops  # the bug we correct
 
 

@@ -1,6 +1,7 @@
 """Performance controller: roofline estimators + historical EWMA."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.configs import get_config
 from repro.core.perf_model import (

@@ -29,7 +29,8 @@ import numpy as np
 from repro.serving.kv_pool import KVBlockPool, blocks_for_tokens
 from repro.serving.prefix_cache import RadixPrefixCache
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 BS = 4
 POOL_BLOCKS = 96
