@@ -14,7 +14,7 @@ ops by bytes / flops / collective bytes (trip-scaled, per chip).
       # persisted prefix-store report (header + per-chain summary)
   PYTHONPATH=src python scripts/diagnose.py --server [arch]
       # step-driven serving introspection: wave-budget plans,
-      # live-slot frontier table, frontend SLO counters
+      # live-slot frontier table, frontend TTFT / ITL percentiles
   PYTHONPATH=src python scripts/diagnose.py --quant
       # per-arch quantization surface (int8 KV-poolable? draft-weight
       # quantizable?) + fused dequant kernel vs reference parity verdict
@@ -204,15 +204,17 @@ def server_report(args: list) -> None:
     """Step-driven serving introspection: drive a live chunked engine a
     few waves and print each wave's budget plan (slot -> mode x width),
     the live-slot frontier table mid-flight, then finish the trace
-    through the always-on frontend and report its SLO counters."""
+    through the always-on frontend and report TTFT and inter-token
+    latency p50/p99 from this function's own submit stamps."""
     import asyncio
 
     import jax
     import numpy as np
 
     from repro.configs import get_smoke_config
-    from repro.launch.serve import AsyncServingFrontend
+    from repro.launch.serve import AsyncServingFrontend, latency_stats
     from repro.serving import EdgeServingEngine, Request, ServeConfig
+    from repro.serving.telemetry import default_clock
 
     arch = args[0] if args else "phi3-medium-14b"
     cfg = get_smoke_config(arch)
@@ -253,12 +255,12 @@ def server_report(args: list) -> None:
     async def finish():
         fe = AsyncServingFrontend(eng)
         await fe.start()
-        fe.submit(req(10, 9))
-        fe.submit(req(11, 21))
+        submitted = [(default_clock(), fe.submit(req(10, 9))),
+                     (default_clock(), fe.submit(req(11, 21)))]
         await fe.shutdown()                  # drains everything live
-        return fe.slo_stats(ttft_slo_ms=500.0, itl_slo_ms=50.0)
+        return latency_stats(submitted)
 
-    print("frontend SLO counters:", json.dumps(asyncio.run(finish())))
+    print("frontend latency (ms):", json.dumps(asyncio.run(finish())))
     st = eng.stats()
     print("engine:", json.dumps({k: st[k] for k in
                                  ("steps", "mixed_waves", "wave_admitted",

@@ -24,8 +24,8 @@ CLI:
 
 ``--mode async`` (default) staggers arrivals over the run and streams
 tokens as they retire; ``--mode drain`` keeps the legacy
-submit-all-then-drain loop.  Reports tokens/sec, TTFT and inter-token
-latency percentiles, and SLO goodput.
+submit-all-then-drain loop.  Reports tokens/sec and TTFT and
+inter-token latency percentiles, timed from the CLI's own submit stamps.
 """
 from __future__ import annotations
 
@@ -52,6 +52,12 @@ class StreamHandle:
     (``None`` sentinel terminates the stream); ``done`` resolves with
     the finished ``Request`` (``req.cancelled`` distinguishes a
     mid-flight cancel from natural completion).
+
+    ``t_submit`` is stamped again when the frontend hands the request
+    to the engine (its inbox drain between steps), so it is that
+    hand-off, not the caller's ``submit``: a caller that wants latency
+    from its own submit keeps its own stamp.  ``t_tokens`` holds the
+    time each token was delivered.
     """
 
     def __init__(self, req: Request, clock: Callable[[], float] = None):
@@ -102,9 +108,6 @@ class AsyncServingFrontend:
         self._task: Optional[asyncio.Task] = None
         self._closing = False
         self._error: Optional[BaseException] = None
-        self.ttft_ms: list[float] = []      # first-token latency/request
-        self.itl_ms: list[float] = []       # every inter-token gap
-        self.cancel_ms: list[float] = []    # cancel() call -> applied
         self.steps = 0
         m = engine.metrics
         m.gauge("frontend.steps", lambda: self.steps)
@@ -168,27 +171,6 @@ class AsyncServingFrontend:
                 path=self.trace_path)
         return out
 
-    def slo_stats(self, ttft_slo_ms: float = 1e9,
-                  itl_slo_ms: float = 1e9) -> dict:
-        """TTFT/ITL percentiles plus goodput under the given SLO."""
-        def pct(xs, q):
-            if not xs:
-                return 0.0
-            s = sorted(xs)
-            return s[min(len(s) - 1, int(q * len(s)))]
-        return {
-            "ttft_p50_ms": round(pct(self.ttft_ms, 0.50), 2),
-            "ttft_p99_ms": round(pct(self.ttft_ms, 0.99), 2),
-            "itl_p50_ms": round(pct(self.itl_ms, 0.50), 2),
-            "itl_p99_ms": round(pct(self.itl_ms, 0.99), 2),
-            "goodput_ttft": round(
-                sum(1 for t in self.ttft_ms if t <= ttft_slo_ms)
-                / max(1, len(self.ttft_ms)), 3),
-            "goodput_itl": round(
-                sum(1 for t in self.itl_ms if t <= itl_slo_ms)
-                / max(1, len(self.itl_ms)), 3),
-        }
-
     # -- internals ----------------------------------------------------
     def _drain_control(self) -> None:
         """Apply queued submits/cancels on the loop thread, between
@@ -204,12 +186,9 @@ class AsyncServingFrontend:
         cancels, self._cancels = self._cancels, []
         for uid, fut, t0 in cancels:
             ok = eng.cancel(uid)
-            if ok:
-                lat_ms = (self._clock() - t0) * 1e3
-                self.cancel_ms.append(lat_ms)
-                if tr is not None:
-                    tr.instant("cancel_applied", tid=FRONTEND_TID,
-                               uid=uid, latency_ms=lat_ms)
+            if ok and tr is not None:
+                tr.instant("cancel_applied", tid=FRONTEND_TID, uid=uid,
+                           latency_ms=(self._clock() - t0) * 1e3)
             if not fut.done():
                 fut.set_result(ok)
             if ok:
@@ -230,10 +209,6 @@ class AsyncServingFrontend:
             while h.delivered < n:
                 tok = int(req.generated[h.delivered])
                 h.delivered += 1
-                if h.t_tokens:
-                    self.itl_ms.append((now - h.t_tokens[-1]) * 1e3)
-                else:
-                    self.ttft_ms.append((now - h.t_submit) * 1e3)
                 h.t_tokens.append(now)
                 h.tokens.put_nowait(tok)
                 cb = self._callbacks.get(uid)
@@ -277,12 +252,14 @@ class AsyncServingFrontend:
         loop = asyncio.get_running_loop()
         eng = self.engine
         while True:
-            self._drain_control()
+            with eng._span("drain", tid=FRONTEND_TID):
+                self._drain_control()
             busy = bool(eng.queue) or bool(eng.active.any())
             if busy:
                 await loop.run_in_executor(None, eng.step)
                 self.steps += 1
-                self._deliver()
+                with eng._span("deliver", tid=FRONTEND_TID):
+                    self._deliver()
                 continue
             if self._inbox or self._cancels:
                 continue                    # new intents — apply now
@@ -292,6 +269,19 @@ class AsyncServingFrontend:
             if self._inbox or self._cancels or self._closing:
                 continue                    # landed before the clear
             await self._wake.wait()
+
+
+def latency_stats(submitted) -> dict:
+    """TTFT and inter-token latency p50/p99 (ms, linearly interpolated)
+    over ``submitted``, pairs of the caller's own submit stamp and the
+    request's ``StreamHandle``; None where there is no sample."""
+    ttft = [(h.t_tokens[0] - t) * 1e3 for t, h in submitted if h.t_tokens]
+    itl = [(b - a) * 1e3 for _, h in submitted
+           for a, b in zip(h.t_tokens, h.t_tokens[1:])]
+    return {f"{name}_p{q}_ms": (round(float(np.percentile(xs, q)), 2)
+                                if xs else None)
+            for name, xs in (("ttft", ttft), ("itl", itl))
+            for q in (50, 99)}
 
 
 # ---------------------------------------------------------------- CLI
@@ -357,14 +347,14 @@ async def _serve_async(eng, reqs, args) -> dict:
     """Open-loop style demo: staggered arrivals into a live frontend."""
     fe = AsyncServingFrontend(eng, trace_path=args.trace)
     await fe.start()
-    handles = []
+    submitted = []
     gap = args.arrival_gap_ms / 1e3
     for req in reqs:
-        handles.append(fe.submit(req))
+        submitted.append((default_clock(), fe.submit(req)))
         if gap:
             await asyncio.sleep(gap)
-    done = [await h.done for h in handles]
-    out = dict(fe.slo_stats())
+    done = [await h.done for _, h in submitted]
+    out = latency_stats(submitted)
     out.update(await fe.shutdown())
     out["requests"] = len(done)
     out["tokens"] = sum(len(r.generated) for r in done)

@@ -421,11 +421,12 @@ class ServeConfig:
     # device sync / retire / publish), per-slot residency tracks and
     # per-request lifecycle events (TTFT decomposition, ITL series,
     # per-round speculative acceptance), exported as Perfetto JSON via
-    # engine.dump_chrome_trace(path).  Tracing only OBSERVES: generated
-    # tokens are bit-identical to an untraced run (the only extra
-    # device call is a value-neutral block_until_ready that fences the
-    # sync span).  The metrics registry (engine.metrics) is always on —
-    # stats() is a compatibility view over it, traced or not.
+    # engine.dump_chrome_trace(path); each phase span is also a
+    # jax.profiler.TraceAnnotation (engine.plan, engine.dispatch, ...),
+    # so a profiler trace puts device idle down to host phases.
+    # Tracing only OBSERVES: generated tokens are bit-identical to an
+    # untraced run.  The metrics registry (engine.metrics) is always on
+    # — stats() is a compatibility view over it, traced or not.
     trace: bool = False
     # monotonic clock the tracer stamps against (None =
     # telemetry.default_clock, i.e. time.perf_counter).  Injectable so
@@ -638,7 +639,9 @@ class EdgeServingEngine:
         # benchmarks/tests reset them by assignment (`eng.steps = 0`) —
         # and the registry reads them through callback gauges.
         self.metrics = MetricsRegistry()
-        self.tracer = Tracer(clock=scfg.trace_clock) if scfg.trace else None
+        self.tracer = (Tracer(clock=scfg.trace_clock,
+                              annotate=jax.profiler.TraceAnnotation)
+                       if scfg.trace else None)
         self._legacy_stats = self._register_metrics()
 
     @property
@@ -1115,32 +1118,30 @@ class EdgeServingEngine:
             if self.paged:
                 blk = self.slot_blocks[slot][:n_wblk]
                 tables[i, :len(blk)] = blk
-        batch = {"tokens": jnp.asarray(prompts)}
-        for k, _ in extras_sig:
-            batch[k] = jnp.asarray(
-                np.stack([np.asarray(r.extras[k]) for r, _ in group]))
-        slots_arr = jnp.asarray([s for _, s in group], jnp.int32)
-        args = [self.params, batch, jnp.asarray(true_len), self.cache,
-                slots_arr]
-        if self.paged:
-            args.append(jnp.asarray(tables))
-        if n_ctx:
-            args.append(jnp.asarray(ctx_len))
+        # prefill_dispatch holds the uploads, prefill_sync the copy
+        # back, as the decode and extend waves' dispatch and sync do
         with self._span("prefill_dispatch", bucket=bucket, rows=m):
+            batch = {"tokens": jnp.asarray(prompts)}
+            for k, _ in extras_sig:
+                batch[k] = jnp.asarray(
+                    np.stack([np.asarray(r.extras[k]) for r, _ in group]))
+            slots_arr = jnp.asarray([s for _, s in group], jnp.int32)
+            args = [self.params, batch, jnp.asarray(true_len), self.cache,
+                    slots_arr]
+            if self.paged:
+                args.append(jnp.asarray(tables))
+            if n_ctx:
+                args.append(jnp.asarray(ctx_len))
             logits, self.cache = self._prefill_fn(bucket, m, extras_sig,
                                                   n_ctx)(*args)
-        if self.tracer is not None:
-            # value-neutral fence: device prefill time vs the host
-            # first-token sampling loop below
-            with self.tracer.span("prefill_sync"):
-                jax.block_until_ready(logits)
         if self.spec is not None:
             # the draft prefills the FULL prompt (it is cheap and never
             # chunks), so catch-up slots are already draft-complete by
             # the time their prompt is consumed
             self.spec.admit_group([r for r, _ in group],
                                   [s for _, s in group])
-        logits_host = np.asarray(logits[:, -1], np.float32)   # (m, V)
+        with self._span("prefill_sync"):
+            logits_host = np.asarray(logits[:, -1], np.float32)  # (m, V)
         for i, (req, slot) in enumerate(group):
             L = int(ctx_len[i])
             n1 = int(true_len[i])
@@ -1415,28 +1416,27 @@ class EdgeServingEngine:
                                      and self.pending[s].size) else
                     ("plain", 1))
                 for s in range(self.scfg.max_slots) if self.active[s]})
-        n_active = int(self.active.sum())
-        if n_active == 0:
-            return 0
-        self.peak_active = max(self.peak_active, n_active)
-        if self.paged:
-            self.peak_pool_used = max(self.peak_pool_used,
-                                      self.pool.num_used)
+            n_active = int(self.active.sum())
+            if n_active == 0:
+                return 0
+            self.peak_active = max(self.peak_active, n_active)
+            if self.paged:
+                self.peak_pool_used = max(self.peak_pool_used,
+                                          self.pool.num_used)
 
-        self._key, sub = jax.random.split(self._key)
-        any_topk = bool((self.topks[self.active] > 0).any())
-        tables = (jnp.asarray(self.block_tables) if self.paged else None)
+        # dispatch holds the uploads, sync the wait and the copy back:
+        # the phases leave no host work of the wave outside a span
         with self._span("dispatch", mode="decode", rows=n_active):
+            self._key, sub = jax.random.split(self._key)
+            any_topk = bool((self.topks[self.active] > 0).any())
+            tables = (jnp.asarray(self.block_tables) if self.paged
+                      else None)
             nxt, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(self.tokens),
                 jnp.asarray(self.pos), jnp.asarray(self.temps),
                 jnp.asarray(self.topks), sub, tables, any_topk=any_topk)
-        if self.tracer is not None:
-            # value-neutral fence: splits device time ("sync") from the
-            # host sampling/retire loop below — tokens are untouched
-            with self.tracer.span("sync"):
-                jax.block_until_ready(nxt)
-        nxt_host = np.asarray(nxt)
+        with self._span("sync"):
+            nxt_host = np.asarray(nxt)
         with self._span("retire"):
             for slot in range(self.scfg.max_slots):
                 if not self.active[slot]:
@@ -1527,13 +1527,18 @@ class EdgeServingEngine:
                 self._cow_guard(spans)
                 plan = {s: p for s, p in plan.items() if self.active[s]}
             self._record_plan(plan)
-        n_active = int(self.active.sum())
-        if n_active == 0:
-            return 0
-        self.peak_active = max(self.peak_active, n_active)
-        if self.paged:
-            self.peak_pool_used = max(self.peak_pool_used,
-                                      self.pool.num_used)
+            n_active = int(self.active.sum())
+            if n_active == 0:
+                return 0
+            self.peak_active = max(self.peak_active, n_active)
+            if self.paged:
+                self.peak_pool_used = max(self.peak_pool_used,
+                                          self.pool.num_used)
+        # positions the wave computes, and those that carry a token
+        # (model.extend_pad_share reads the padding off these two)
+        n_fed = sum(v for _, v in plan.values())
+        self.metrics.counter("engine.extend_positions").inc(B * K)
+        self.metrics.counter("engine.extend_fed").inc(n_fed)
 
         spec_slots = [s for s, (m, _) in plan.items() if m == "spec"]
         proposals, dists = {}, {}
@@ -1547,35 +1552,30 @@ class EdgeServingEngine:
                     spec_slots, self.tokens[:, 0], self.temps,
                     self.topks, k_spec, self._rng)
 
-        fed = np.zeros((B, K), np.int32)
-        valid = np.ones((B,), np.int32)
-        for s, (mode, v) in plan.items():
-            seq = [int(self.tokens[s, 0])]
-            if mode == "catch":
-                seq += [int(t) for t in self.pending[s][:v - 1]]
-            elif mode == "spec":
-                seq += proposals[s][:v - 1]
-            fed[s, :len(seq)] = seq
-            fed[s, len(seq):] = seq[-1]       # pad (write-dropped)
-            valid[s] = v
-
-        tables = jnp.asarray(self.block_tables) if self.paged else None
         # all-greedy waves ship only the (B, K) argmax ids
         need_logits = bool((self.temps[self.active] > 0).any())
         with self._span("dispatch", mode="extend", rows=n_active,
-                        fed_tokens=int(sum(v for _, v in plan.values()))):
+                        fed_tokens=n_fed):
+            fed = np.zeros((B, K), np.int32)
+            valid = np.ones((B,), np.int32)
+            for s, (mode, v) in plan.items():
+                seq = [int(self.tokens[s, 0])]
+                if mode == "catch":
+                    seq += [int(t) for t in self.pending[s][:v - 1]]
+                elif mode == "spec":
+                    seq += proposals[s][:v - 1]
+                fed[s, :len(seq)] = seq
+                fed[s, len(seq):] = seq[-1]       # pad (write-dropped)
+                valid[s] = v
+            tables = jnp.asarray(self.block_tables) if self.paged else None
             greedy, logits, self.cache = self._extend(
                 self.params, self.cache, jnp.asarray(fed),
                 jnp.asarray(self.pos), jnp.asarray(valid), tables,
                 need_logits=need_logits)
-        if self.tracer is not None:
-            # value-neutral fence separating device compute from the
-            # host acceptance/sampling loop
-            with self.tracer.span("sync"):
-                jax.block_until_ready(greedy)
-        greedy = np.asarray(greedy)                      # (B, K)
-        logits = (np.asarray(logits, np.float32) if need_logits
-                  else None)                             # (B, K, V)
+        with self._span("sync"):
+            greedy = np.asarray(greedy)                  # (B, K)
+            logits = (np.asarray(logits, np.float32) if need_logits
+                      else None)                         # (B, K, V)
 
         def sample(s, row, temp, top_k):
             if temp <= 0:

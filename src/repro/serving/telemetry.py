@@ -17,10 +17,12 @@ stack reports through:
 * ``Tracer`` — span/event recorder against an injectable monotonic
   clock (``ServeConfig.trace_clock``): engine phases (admit / plan /
   draft / dispatch / device sync / retire / publish) become nested
-  spans on an engine track, each slot gets its own track carrying the
-  resident request's lifecycle, and per-request events (submit /
-  admitted / prefill-chunk / first-token / spec-round / preempt /
-  resume / CoW-fork / cancel / finish) yield an exact TTFT
+  spans on an engine track (and, through the engine's ``annotate``
+  hook, ``engine.*`` / ``frontend.*`` host annotations in the JAX
+  profiler's trace, on the device's clock), each slot gets its own
+  track carrying the resident request's lifecycle, and per-request
+  events (submit / admitted / prefill-chunk / first-token / spec-round
+  / preempt / resume / CoW-fork / cancel / finish) yield an exact TTFT
   decomposition: ``queue_wait + prefill + first_wave == ttft`` by
   construction (the three segments share their boundary stamps).
 * ``Tracer.dump_chrome_trace(path)`` — Chrome-trace/Perfetto JSON
@@ -51,7 +53,7 @@ on offenders.
 from __future__ import annotations
 
 import json
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Callable, Optional
 
 import time as _time
@@ -218,10 +220,18 @@ class Tracer:
     (``req_event``) are additionally kept in arrival order per uid so
     ``request_summaries()`` can compute the TTFT decomposition and ITL
     series without re-parsing the Chrome events.
+
+    ``annotate``, when given, is a context-manager factory called as
+    ``annotate(f"{track}.{name}", **args)`` around every ``span`` (the
+    engine passes ``jax.profiler.TraceAnnotation``), so the phases also
+    land in a profiler trace on the device's clock: ``engine.plan``,
+    ``engine.dispatch``, ``frontend.deliver``, ...
     """
 
-    def __init__(self, clock: Optional[Callable[[], float]] = None):
+    def __init__(self, clock: Optional[Callable[[], float]] = None,
+                 annotate: Optional[Callable[..., object]] = None):
         self.clock = clock if clock is not None else default_clock
+        self.annotate = annotate
         self._t0 = self.clock()
         self.events: list[dict] = []
         self._stacks: dict[int, list] = {}        # tid -> open X spans
@@ -242,18 +252,24 @@ class Tracer:
     # -- spans ---------------------------------------------------------
     @contextmanager
     def span(self, name: str, tid: int = ENGINE_TID, **args):
-        """Nested phase span (complete ``"X"`` event on exit)."""
-        t0 = self.now_us()
-        stack = self._stacks.setdefault(tid, [])
-        stack.append(name)
-        try:
-            yield
-        finally:
-            stack.pop()
-            self.events.append({
-                "ph": "X", "name": name, "cat": "phase", "pid": _PID,
-                "tid": tid, "ts": t0, "dur": self.now_us() - t0,
-                **({"args": args} if args else {})})
+        """Nested phase span (complete ``"X"`` event on exit), also
+        entered as ``annotate(f"{track}.{name}", **args)`` when the
+        tracer has an ``annotate`` hook."""
+        # the span's own bookkeeping runs inside the annotation, so a
+        # profiler trace puts it down to this phase, not between phases
+        with (nullcontext() if self.annotate is None else
+              self.annotate(f"{self._tracks[tid]}.{name}", **args)):
+            t0 = self.now_us()
+            stack = self._stacks.setdefault(tid, [])
+            stack.append(name)
+            try:
+                yield
+            finally:
+                stack.pop()
+                self.events.append({
+                    "ph": "X", "name": name, "cat": "phase", "pid": _PID,
+                    "tid": tid, "ts": t0, "dur": self.now_us() - t0,
+                    **({"args": args} if args else {})})
 
     def begin(self, name: str, tid: int, **args) -> None:
         """Open-ended span (slot residency) — closed by ``end(tid)``."""

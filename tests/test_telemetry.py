@@ -358,3 +358,163 @@ def test_dump_requires_trace_enabled(model):
         max_slots=2, max_len=96, prefill_buckets=(8, 16, 32)))
     with pytest.raises(ValueError):
         eng.dump_chrome_trace("/tmp/never.json")
+
+
+# ---------------------------------------------------------------------------
+# phase spans as profiler annotations; extend-wave padding counters
+# ---------------------------------------------------------------------------
+
+def _chunked_engine(model, **kw):
+    cfg, params = model
+    return cfg, EdgeServingEngine(cfg, params, ServeConfig(
+        max_slots=4, max_len=96, prefill_buckets=(8, 16, 32), seed=3,
+        chunked_prefill=True, catch_chunk=8, **kw))
+
+
+def _chunked_requests():
+    rng = np.random.default_rng(7)
+    return [Request(uid=u, prompt=rng.integers(0, 64, n, dtype=np.int32),
+                    max_new_tokens=4) for u, n in ((0, 20), (1, 5))]
+
+
+def test_extend_wave_counters_exact(model):
+    """4 slots x K=8 positions per extend wave.  The 20-token prompt
+    catches up 8, 8, 4 tokens; the 5-token prompt feeds 5, then rides
+    along at width 1 while the other still catches up.  Three extend
+    waves, then decode waves (which count nothing)."""
+    _, eng = _chunked_engine(model)
+    for r in _chunked_requests():
+        eng.submit(r)
+    eng.run_until_drained()
+    snap = eng.metrics.collect()
+    assert snap["engine.extend_positions"] == 3 * 4 * 8
+    assert snap["engine.extend_fed"] == (8 + 5) + (8 + 1) + (4 + 1)
+    assert not any(k.startswith("extend") for k in eng.stats())
+
+
+class _Slow:
+    """Stands in for a module the engine imports (``np``, ``jnp``,
+    ``jax.random``): every ``asarray`` / ``split`` call advances the
+    fake clock by ``cost`` first, so host work the phases leave
+    uncovered shows as a hole in the step."""
+
+    def __init__(self, mod, clock_state, cost, names):
+        self._mod, self._t, self._cost, self._names = (mod, clock_state,
+                                                       cost, names)
+
+    def __getattr__(self, name):
+        fn = getattr(self._mod, name)
+        if name not in self._names:
+            return fn
+
+        def slow(*a, **k):
+            self._t[0] += self._cost
+            return fn(*a, **k)
+        return slow
+
+
+def test_phases_cover_each_step(model, monkeypatch):
+    """Every engine step is covered by its phases: the only gaps left
+    are the single clock tick between one phase's end stamp and the
+    next one's start stamp (and at the step's two edges).  Host work
+    between phases (the uploads, the PRNG split, the copy back)
+    advances the fake clock and would open a wider hole."""
+    from repro.serving import engine as engine_mod
+    tick, cost = 1e-6, 1e-3
+    t = [0.0]
+
+    def clock():
+        t[0] += tick
+        return t[0]
+
+    monkeypatch.setattr(engine_mod, "np",
+                        _Slow(np, t, cost, {"asarray"}))
+    monkeypatch.setattr(engine_mod, "jnp",
+                        _Slow(engine_mod.jnp, t, cost, {"asarray"}))
+    fake_jax = _Slow(jax, t, cost, ())
+    fake_jax.random = _Slow(jax.random, t, cost, {"split"})
+    monkeypatch.setattr(engine_mod, "jax", fake_jax)
+    _, eng = _chunked_engine(model, trace=True, trace_clock=clock)
+    for r in _chunked_requests():
+        eng.submit(r)
+    eng.run_until_drained()
+    phases = [e for e in eng.tracer.events
+              if e["ph"] == "X" and e["tid"] == 0]
+    steps = [e for e in phases if e["name"] == "step"]
+    assert len(steps) == eng.steps > 3
+    names = set()
+    for st in steps:
+        a, b = st["ts"], st["ts"] + st["dur"]
+        inner = sorted((e["ts"], e["ts"] + e["dur"]) for e in phases
+                       if e is not st and a <= e["ts"] <= b)
+        names |= {e["name"] for e in phases
+                  if e is not st and a <= e["ts"] <= b}
+        edge = a
+        for x, y in inner:
+            assert x - edge <= tick * 1e6 * 1.5, (st, x - edge)
+            edge = max(edge, y)
+        assert b - edge <= tick * 1e6 * 1.5
+    assert {"admit", "plan", "dispatch", "sync", "retire",
+            "publish"} <= names
+
+
+def test_phases_reach_the_profiler_trace(model, tmp_path):
+    """With tracing on, each phase is a host annotation in the JAX
+    profiler's trace: every ``engine.*`` phase lies inside an
+    ``engine.step`` on the same thread, and the step carries its
+    index."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    _, eng = _chunked_engine(model, trace=True)
+    for r in _chunked_requests():
+        eng.submit(r)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run_until_drained()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    steps, phases = {}, []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if not ev.name.startswith("engine."):
+                    continue
+                iv = (ev.start_ns, ev.start_ns + ev.duration_ns)
+                if ev.name == "engine.step":
+                    steps.setdefault((plane.name, line.name), []).append(iv)
+                    assert "step" in dict(ev.stats)
+                else:
+                    phases.append(((plane.name, line.name), ev.name, iv))
+    assert sum(map(len, steps.values())) == eng.steps
+    assert {n for _, n, _ in phases} >= {
+        "engine.admit", "engine.plan", "engine.dispatch", "engine.sync",
+        "engine.retire", "engine.publish"}
+    for where, name, (a, b) in phases:
+        assert any(x <= a and b <= y for x, y in steps[where]), name
+
+
+def test_untraced_run_never_annotates(model, monkeypatch):
+    """With tracing off neither the engine nor the frontend creates a
+    profiler annotation."""
+    import asyncio
+
+    from repro.launch.serve import AsyncServingFrontend
+
+    def boom(*a, **k):
+        raise AssertionError("annotation created with tracing off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", boom)
+    _, eng = _chunked_engine(model)
+
+    async def serve():
+        fe = AsyncServingFrontend(eng)
+        await fe.start()
+        handles = [fe.submit(r) for r in _chunked_requests()]
+        for h in handles:
+            await h.done
+        await fe.shutdown()
+
+    asyncio.run(serve())
+    assert eng.tracer is None
+    assert sorted(len(r.generated) for r in eng.completed) == [4, 4]
