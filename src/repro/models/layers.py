@@ -19,7 +19,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -743,6 +743,70 @@ def attention_prefill_paged(cfg: ModelConfig, params, x, positions, pages,
                                 jnp.asarray(ctx_len, jnp.int32))
 
 
+def _decode_read(cfg: ModelConfig, q, pages, block_tables, pos, dtype,
+                 use_pallas: bool):
+    """One query per row against the pool AS WRITTEN: the positions
+    ``<= pos`` of the row's table.  q: (B, 1, H, hd); returns
+    (B, 1, H, hd) in ``dtype``.  ``use_pallas`` reads through the
+    Pallas ``paged_attention`` kernel, otherwise through the gathered
+    logical view (the same values and mask)."""
+    B = q.shape[0]
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
+    if use_pallas:
+        from repro.kernels import ops as kernel_ops
+        out = kernel_ops.paged_attention(
+            q[:, 0], pages["k"], pages["v"], block_tables, pos + 1,
+            scale=scale, softcap=cfg.attn_logit_softcap,
+            k_scale=pages.get("k_scale"), v_scale=pages.get("v_scale"))
+        return out[:, None].astype(dtype)
+    kg, vg = gather_kv_pages(pages, block_tables)
+    bs = pages["k"].shape[1]
+    t = jnp.arange(block_tables.shape[1] * bs, dtype=jnp.int32)
+    allocated = jnp.repeat(block_tables >= 0, bs, axis=1)
+    valid = allocated & (t[None, :] <= pos[:, None])
+    out = attention_weights_and_out(
+        q.reshape(B, 1, K, H // K, hd), kg.astype(dtype), vg.astype(dtype),
+        valid[:, None, None, None, :], scale=scale,
+        softcap=cfg.attn_logit_softcap)
+    return out.reshape(B, 1, H, hd)
+
+
+def _extend_read(cfg: ModelConfig, q, k, v, pages, block_tables, pos, dtype,
+                 use_pallas: bool):
+    """``S`` queries per row against the PRE-WRITE pool — the positions
+    ``< pos`` of the row's table — and, causally, against the row's own
+    ``S`` new tokens (k, v: (B, S, K, hd)).  q: (B, S, H, hd); returns
+    (B, S, H, hd) in ``dtype``.  On an int8 pool ``use_pallas`` reads
+    through the fused dequant ``paged_extend_attention`` kernel; on any
+    other pool the gathered view is read (bit-exact)."""
+    B, S = q.shape[:2]
+    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
+    if kv_pages_quantized(pages) and use_pallas:
+        from repro.kernels import ops as kernel_ops
+        out = kernel_ops.paged_extend_attention(
+            q, pages["k"], pages["v"], k, v, block_tables, pos,
+            scale=scale, softcap=cfg.attn_logit_softcap,
+            k_scale=pages["k_scale"], v_scale=pages["v_scale"])
+        return out.astype(dtype)
+    ck, cv = gather_kv_pages(pages, block_tables)
+    bs = pages["k"].shape[1]
+    L = block_tables.shape[1] * bs
+    t = jnp.arange(L, dtype=jnp.int32)
+    allocated = jnp.repeat(block_tables >= 0, bs, axis=1)
+    ctx_ok = allocated & (t[None, :] < pos[:, None])               # (B, L)
+    mask = jnp.concatenate(
+        [jnp.broadcast_to(ctx_ok[:, None, :], (B, S, L)),
+         jnp.broadcast_to(causal_mask(S, S), (B, S, S))], axis=-1)
+    k_all = jnp.concatenate([ck.astype(dtype), k], axis=1)
+    v_all = jnp.concatenate([cv.astype(dtype), v], axis=1)
+    out = attention_weights_and_out(
+        q.reshape(B, S, K, H // K, hd), k_all, v_all, mask[:, None, None],
+        scale=scale, softcap=cfg.attn_logit_softcap)
+    return out.reshape(B, S, H, hd)
+
+
 def attention_decode_paged(cfg: ModelConfig, params, x, cache, pos,
                            block_tables, *, use_pallas: bool = False):
     """Single-token decode against a paged KV pool (GLOBAL layers only —
@@ -772,9 +836,6 @@ def attention_decode_paged(cfg: ModelConfig, params, x, cache, pos,
     B, S, d = x.shape
     assert S == 1
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = H // K
-    scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
 
     q, knew, vnew = _decode_project(cfg, params, x, pos, is_global=True)
 
@@ -782,52 +843,35 @@ def attention_decode_paged(cfg: ModelConfig, params, x, cache, pos,
     blk, off = pos // bs, pos % bs
     phys = block_tables[jnp.arange(B), blk]
     wphys = jnp.where(phys >= 0, phys, nB)       # nB is OOB => dropped
-    quant = kv_pages_quantized(cache)
-    if quant:
+    if kv_pages_quantized(cache):
         kq1, ks1 = quantize_kv(knew[:, 0])
         vq1, vs1 = quantize_kv(vnew[:, 0])
-        kc = cache["k"].at[wphys, off].set(kq1, mode="drop")
-        vc = cache["v"].at[wphys, off].set(vq1, mode="drop")
-        kcs = cache["k_scale"].at[wphys, off].set(ks1, mode="drop")
-        vcs = cache["v_scale"].at[wphys, off].set(vs1, mode="drop")
-        new_cache = {"k": kc, "v": vc, "k_scale": kcs, "v_scale": vcs}
+        new_cache = _scatter_at(cache, wphys, off, kq1, vq1, ks1, vs1)
     else:
-        kc = cache["k"].at[wphys, off].set(
-            knew[:, 0].astype(cache["k"].dtype), mode="drop")
-        vc = cache["v"].at[wphys, off].set(
-            vnew[:, 0].astype(cache["v"].dtype), mode="drop")
-        new_cache = {"k": kc, "v": vc}
-
-    if use_pallas:
-        from repro.kernels import ops as kernel_ops
-        out = kernel_ops.paged_attention(
-            q[:, 0], kc, vc, block_tables, pos + 1, scale=scale,
-            softcap=cfg.attn_logit_softcap,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"))
-        o = weight_einsum("bshq,hqd->bsd", out[:, None].astype(x.dtype),
-                          params["wo"])
-        return o, new_cache
-
-    # gather the logical view: (B, n_blk*bs, K, hd)
-    bt = jnp.clip(block_tables, 0, nB - 1)
-    kg = kc[bt].reshape(B, -1, K, hd)
-    vg = vc[bt].reshape(B, -1, K, hd)
-    if quant:
-        kg = dequantize_kv(kg, kcs[bt].reshape(B, -1, K))
-        vg = dequantize_kv(vg, vcs[bt].reshape(B, -1, K))
-    t = jnp.arange(block_tables.shape[1] * bs, dtype=jnp.int32)
-    allocated = jnp.repeat(block_tables >= 0, bs, axis=1)
-    valid = allocated & (t[None, :] <= pos[:, None])
-    mask = valid[:, None, None, None, :]          # (B,1,1,1,L)
-
-    qg = q.reshape(B, 1, K, G, hd)
-    out = attention_weights_and_out(qg, kg.astype(x.dtype),
-                                    vg.astype(x.dtype), mask, scale=scale,
-                                    softcap=cfg.attn_logit_softcap)
-    o = weight_einsum("bshq,hqd->bsd", out.reshape(B, 1, H, hd),
-                      params["wo"])
+        new_cache = _scatter_at(cache, wphys, off, knew[:, 0], vnew[:, 0])
+    out = _decode_read(cfg, q, new_cache, block_tables, pos, x.dtype,
+                       use_pallas)
+    o = weight_einsum("bshq,hqd->bsd", out, params["wo"])
     return o, new_cache
+
+
+def _scatter_at(pages, tgt, off, k, v, k_scale=None, v_scale=None):
+    """Write token K/V at (page ``tgt``, offset ``off``) pairs of any
+    matching index shape (``tgt`` = the pool's page count drops the
+    write).  With ``k_scale``/``v_scale`` the K/V are already int8 and
+    their scales land in the pool's scale leaves."""
+    new = {
+        "k": pages["k"].at[tgt, off].set(k.astype(pages["k"].dtype),
+                                         mode="drop"),
+        "v": pages["v"].at[tgt, off].set(v.astype(pages["v"].dtype),
+                                         mode="drop"),
+    }
+    if k_scale is not None:
+        new["k_scale"] = pages["k_scale"].at[tgt, off].set(k_scale,
+                                                           mode="drop")
+        new["v_scale"] = pages["v_scale"].at[tgt, off].set(v_scale,
+                                                           mode="drop")
+    return new
 
 
 def scatter_kv_tokens(pages, k, v, block_tables, pos, valid_len=None):
@@ -851,12 +895,7 @@ def scatter_kv_tokens(pages, k, v, block_tables, pos, valid_len=None):
                                      pos, valid_len)
     tgt, off = _token_write_targets(pages, k.shape[0], k.shape[1],
                                     block_tables, pos, valid_len)
-    return {
-        "k": pages["k"].at[tgt, off].set(k.astype(pages["k"].dtype),
-                                         mode="drop"),
-        "v": pages["v"].at[tgt, off].set(v.astype(pages["v"].dtype),
-                                         mode="drop"),
-    }
+    return _scatter_at(pages, tgt, off, k, v)
 
 
 def _token_write_targets(pages, B, S, block_tables, pos, valid_len):
@@ -883,25 +922,52 @@ def _scatter_tokens_quant(pages, kq, ks, vq, vs, block_tables, pos,
     the scale would be applied twice)."""
     tgt, off = _token_write_targets(pages, kq.shape[0], kq.shape[1],
                                     block_tables, pos, valid_len)
-    return {
-        "k": pages["k"].at[tgt, off].set(kq, mode="drop"),
-        "v": pages["v"].at[tgt, off].set(vq, mode="drop"),
-        "k_scale": pages["k_scale"].at[tgt, off].set(ks, mode="drop"),
-        "v_scale": pages["v_scale"].at[tgt, off].set(vs, mode="drop"),
-    }
+    return _scatter_at(pages, tgt, off, kq, vq, ks, vs)
+
+
+class NarrowRows(NamedTuple):
+    """The decode-shaped row group of a packed paged extend wave: one
+    token per slot at the slot's write position, read through the
+    slot's block table.  ``valid`` is 1 where the slot feeds this token
+    and 0 where it feeds none here (an idle slot, or one whose span
+    rides a wide row): a 0 row drops its K/V write and the caller
+    discards its outputs."""
+    tokens: jax.Array            # (n, 1) int32
+    pos: jax.Array               # (n,) int32
+    block_tables: jax.Array      # (n, n_blk) int32
+    valid: jax.Array             # (n,) int32, 0 or 1
+
+
+def wave_tokens(tokens, narrow: Optional[NarrowRows] = None):
+    """The token ids an extend wave embeds: ``tokens`` (R, S) as given,
+    or, with a narrow group, both groups flattened narrow-first into one
+    (1, n + R*S) row — the layout every token-wise layer then runs on."""
+    if narrow is None:
+        return tokens
+    return jnp.concatenate([narrow.tokens.reshape(-1),
+                            tokens.reshape(-1)])[None]
+
+
+def split_wave(t, narrow: NarrowRows, rows: int):
+    """Inverse of ``wave_tokens`` for a per-token array (1, n + R*S, ...):
+    (narrow part (n, 1, ...), wide part (R, S, ...))."""
+    n = narrow.pos.shape[0]
+    t = t[0]
+    return t[:n, None], t[n:].reshape(rows, -1, *t.shape[1:])
 
 
 def attention_extend_paged(cfg: ModelConfig, params, x, pos, pages,
                            block_tables, valid_len=None, *,
-                           use_pallas: bool = False):
+                           use_pallas: bool = False,
+                           narrow: Optional[NarrowRows] = None):
     """Multi-token decode against the paged pool: score ``S`` proposed /
-    teacher-forced tokens in ONE call (speculative verify, chunked
-    catch-up prefill) — the causal-suffix machinery of
+    teacher-forced tokens per row in ONE call (speculative verify,
+    chunked catch-up prefill) — the causal-suffix machinery of
     ``attention_prefill_paged`` applied at an arbitrary mid-block
     position.
 
-    x: (B, S, d) token activations at absolute positions ``pos + i``;
-    pages: this layer's pool dict; block_tables: (B, n_blk) the slot's
+    x: (R, S, d) token activations at absolute positions ``pos + i``;
+    pages: this layer's pool dict; block_tables: (R, n_blk) the row's
     FULL table (context and write span in one view).  The context is the
     PRE-WRITE gathered view masked strictly below ``pos`` — stale
     entries from a previously rejected speculation (positions >= pos)
@@ -910,70 +976,71 @@ def attention_extend_paged(cfg: ModelConfig, params, x, pos, pages,
     suffix.  K/V for rows ``i < valid_len`` is then scattered into the
     pages at ``pos + i`` (see ``scatter_kv_tokens``; rejected proposals
     stay written but stay masked until overwritten in sequence order).
-    Returns (out (B, S, d), new_pages).
+    Returns (out (R, S, d), new_pages).
+
+    With ``narrow`` the wave is PACKED: x is (1, n + R*S, d), the n
+    width-1 rows of ``narrow`` first (``wave_tokens``), then the R wide
+    rows above.  The projections run once over every token; the wide
+    rows read the pre-write pool as above, both groups' K/V land in one
+    scatter, and the narrow rows then read the written pool exactly as
+    ``attention_decode_paged`` does (the Pallas ``paged_attention``
+    kernel under ``use_pallas``).  Returns (out (1, n + R*S, d),
+    new_pages).
 
     On a quantized pool the suffix attends the int8 ROUND-TRIP of its
     own K/V — the same values every later read of those pages sees —
-    and ``use_pallas=True`` swaps the gather read for the fused
-    dequant ``kernels.flash_attention.paged_extend_attention`` kernel
-    (pages never materialise in f32; the kernel receives the already
-    round-tripped suffix so both reads agree to accumulation
-    tolerance).  On an f32 pool ``use_pallas`` is ignored and the path
-    stays bit-exact.
+    and ``use_pallas=True`` swaps the wide rows' gather read for the
+    fused dequant ``kernels.flash_attention.paged_extend_attention``
+    kernel (pages never materialise in f32; the kernel receives the
+    already round-tripped suffix so both reads agree to accumulation
+    tolerance).  On an f32 pool the wide rows ignore ``use_pallas`` and
+    stay bit-exact.
     """
-    B, S, d = x.shape
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = H // K
-    scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    H, hd = cfg.num_heads, cfg.head_dim
+    n = 0 if narrow is None else narrow.pos.shape[0]
+    R = block_tables.shape[0]
+    S = (x.shape[1] - n) // R if narrow is not None else x.shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (R,))
     positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    if narrow is not None:
+        positions = jnp.concatenate([narrow.pos,
+                                     positions.reshape(-1)])[None]
 
     q, k, v = _project_seq(cfg, params, x, positions, is_global=True)
-
-    quant = kv_pages_quantized(pages)
-    if quant:
+    scales = ()
+    if kv_pages_quantized(pages):
         kq, ks = quantize_kv(k)
         vq, vs = quantize_kv(v)
         k = dequantize_kv(kq, ks, k.dtype)
         v = dequantize_kv(vq, vs, v.dtype)
-        new_pages = _scatter_tokens_quant(pages, kq, ks, vq, vs,
-                                          block_tables, pos, valid_len)
+        written, scales = (kq, vq), (ks, vs)
     else:
-        new_pages = scatter_kv_tokens(pages, k, v, block_tables, pos,
-                                      valid_len)
+        written = (k, v)
 
-    if quant and use_pallas:
-        from repro.kernels import ops as kernel_ops
-        out = kernel_ops.paged_extend_attention(
-            q, pages["k"], pages["v"], k, v, block_tables, pos,
-            scale=scale, softcap=cfg.attn_logit_softcap,
-            k_scale=pages["k_scale"], v_scale=pages["v_scale"])
-        o = weight_einsum("bshq,hqd->bsd", out.astype(x.dtype),
-                          params["wo"])
-        return o, new_pages
+    def wide(t):
+        return t if narrow is None else split_wave(t, narrow, R)[1]
 
-    nB, bs = pages["k"].shape[0], pages["k"].shape[1]
-    bt = jnp.clip(block_tables, 0, nB - 1)
-    ck = pages["k"][bt].reshape(B, -1, K, hd)
-    cv = pages["v"][bt].reshape(B, -1, K, hd)
-    if quant:
-        ck = dequantize_kv(ck, pages["k_scale"][bt].reshape(B, -1, K))
-        cv = dequantize_kv(cv, pages["v_scale"][bt].reshape(B, -1, K))
-    L = block_tables.shape[1] * bs
-    t = jnp.arange(L, dtype=jnp.int32)
-    allocated = jnp.repeat(block_tables >= 0, bs, axis=1)
-    ctx_ok = allocated & (t[None, :] < pos[:, None])               # (B, L)
-    mask = jnp.concatenate(
-        [jnp.broadcast_to(ctx_ok[:, None, :], (B, S, L)),
-         jnp.broadcast_to(causal_mask(S, S), (B, S, S))], axis=-1)
-    k_all = jnp.concatenate([ck.astype(x.dtype), k], axis=1)
-    v_all = jnp.concatenate([cv.astype(x.dtype), v], axis=1)
-    qg = q.reshape(B, S, K, G, hd)
-    out = attention_weights_and_out(qg, k_all, v_all, mask[:, None, None],
-                                    scale=scale,
-                                    softcap=cfg.attn_logit_softcap)
-    o = weight_einsum("bshq,hqd->bsd", out.reshape(B, S, H, hd),
-                      params["wo"])
+    # the wide rows read the pool before this wave's writes land
+    out = _extend_read(cfg, wide(q), wide(k), wide(v), pages, block_tables,
+                       pos, x.dtype, use_pallas)
+    tgt, off = _token_write_targets(pages, R, S, block_tables, pos,
+                                    valid_len)
+    if narrow is None:
+        new_pages = _scatter_at(pages, tgt, off, *written, *scales)
+    else:
+        ntgt, noff = _token_write_targets(pages, n, 1, narrow.block_tables,
+                                          narrow.pos, narrow.valid)
+        flat = [t[0] for t in written + scales]
+        new_pages = _scatter_at(
+            pages, jnp.concatenate([ntgt.reshape(-1), tgt.reshape(-1)]),
+            jnp.concatenate([noff.reshape(-1), off.reshape(-1)]), *flat)
+        # the narrow rows read the pool after it, as a decode wave does
+        out_n = _decode_read(cfg, split_wave(q, narrow, R)[0], new_pages,
+                             narrow.block_tables, narrow.pos, x.dtype,
+                             use_pallas)
+        out = jnp.concatenate([out_n.reshape(n, H, hd),
+                               out.reshape(R * S, H, hd)])[None]
+    o = weight_einsum("bshq,hqd->bsd", out, params["wo"])
     return o, new_pages
 
 

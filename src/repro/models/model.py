@@ -16,6 +16,7 @@ Every family exposes the same five entry points, dispatched on
                   cache, slots=..., ...)       -> (logits, cache)
     extend_paged(cfg, params, cache, toks[B,S],
                  pos, block_tables)            -> (logits[B,S,V], cache)
+                 (or the packed layout, see ``extend_packable``)
     prefix_sharable(cfg)                       -> bool (radix cache ok?)
     extendable(cfg) / spec_decodable(cfg)      -> bool (multi-token
                                                   extend / spec verify?)
@@ -190,12 +191,14 @@ def decode_step_paged(cfg: ModelConfig, params: Params, cache, tokens, pos,
 
 
 def extend_paged(cfg: ModelConfig, params: Params, cache, tokens, pos,
-                 block_tables, valid_len=None, use_pallas: bool = False):
-    """Score S tokens against the paged cache in ONE jitted call —
-    the multi-token twin of ``decode_step_paged`` used for speculative
+                 block_tables, valid_len=None, use_pallas: bool = False,
+                 narrow=None):
+    """Score S tokens per row against the paged cache in ONE jitted call
+    — the multi-token twin of ``decode_step_paged`` used for speculative
     verify and chunked catch-up prefill.
 
-    tokens: (B, S) int32 at absolute positions ``pos + i`` (pos: (B,)
+    The **(B, S) layout** (``narrow=None``, every extendable family):
+    tokens (B, S) int32 at absolute positions ``pos + i`` (pos: (B,)
     per-slot write frontiers); block_tables: the slot's full (B, n_blk)
     table (context AND write span).  Returns (logits (B, S, V),
     new_cache): row ``i`` is the next-token distribution after
@@ -203,15 +206,26 @@ def extend_paged(cfg: ModelConfig, params: Params, cache, tokens, pos,
     below ``pos`` (pre-write view), so stale K/V from a rejected
     speculation is invisible and rollback is pure bookkeeping; K/V for
     rows ``i < valid_len`` is written at ``pos + i`` (pad rows drop).
+
+    The **packed layout** (``narrow``, a ``layers.NarrowRows``; only
+    where ``extend_packable``): ``tokens`` (R, S), ``pos``,
+    ``block_tables`` and ``valid_len`` are R wide rows as above, and
+    ``narrow`` holds one width-1 row per slot, read as the decode wave
+    reads it.  A wave then computes n + R*S positions instead of n*S.
+    Returns ((narrow logits (n, 1, V), wide logits (R, S, V)),
+    new_cache).
+
     ssm/hybrid raise NotImplementedError — gate callers on
     ``extendable`` / ``spec_decodable``.  ``use_pallas=True`` reads a
     QUANTIZED pool through the fused dequant
     ``kernels.flash_attention.paged_extend_attention`` kernel (no-op on
-    an f32 pool, which keeps that path bit-exact).
+    an f32 pool, which keeps that path bit-exact), and the narrow rows
+    through ``paged_attention``.
     """
+    kw = {} if narrow is None else {"narrow": narrow}
     return family_module(cfg).extend_paged(cfg, params, cache, tokens,
                                            pos, block_tables, valid_len,
-                                           use_pallas=use_pallas)
+                                           use_pallas=use_pallas, **kw)
 
 
 def extend(cfg: ModelConfig, params: Params, cache, tokens, pos,
@@ -232,6 +246,19 @@ def extendable(cfg: ModelConfig) -> bool:
     recurrent families (ssm, hybrid), whose state cannot be advanced S
     tokens and later truncated."""
     return cfg.family in ("dense", "moe", "vlm", "encdec")
+
+
+def extend_packable(cfg: ModelConfig) -> bool:
+    """Does ``extend_paged`` take the packed layout (width-1 slots in a
+    decode-shaped row group, wider spans in a bucket of wide rows)?
+    True for the uniform paged trunks: dense and vlm without local ring
+    layers (``pattern_period <= 1``), and moe.  Local-ring families keep
+    the (B, S) wave (their rings are per-slot dense state the wide rows
+    would have to gather), as does encdec (its cross-attention K/V is
+    per slot)."""
+    if cfg.family in ("dense", "vlm"):
+        return cfg.pattern_period <= 1
+    return cfg.family == "moe"
 
 
 def spec_decodable(cfg: ModelConfig) -> bool:

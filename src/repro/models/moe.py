@@ -47,10 +47,13 @@ def capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max(c, 1)
 
 
-def moe_mlp(cfg: ModelConfig, p: Params, x, token_mask=None):
+def moe_mlp(cfg: ModelConfig, p: Params, x, token_mask=None,
+            expert_capacity: Optional[int] = None):
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar f32).
     ``token_mask``: optional (B, S) bool — False marks padding tokens
     excluded from expert capacity (see ``_moe_tokens``).
+    ``expert_capacity``: optional slots per expert (per row under
+    ``moe_rowwise``) in place of ``capacity`` of the token count.
 
     cfg.moe_rowwise: dispatch each sequence independently (vmap over
     batch) — the expert buffers then carry the batch dim and shard over
@@ -64,27 +67,30 @@ def moe_mlp(cfg: ModelConfig, p: Params, x, token_mask=None):
         token_mask = token_mask.reshape(B, S)
     if cfg.moe_rowwise:
         out, aux = jax.vmap(
-            lambda row, m: _moe_tokens(cfg, p, row, m))(
+            lambda row, m: _moe_tokens(cfg, p, row, m, expert_capacity))(
                 x.reshape(B, S, d),
                 (jnp.ones((B, S), bool) if token_mask is None
                  else token_mask))
         return out.reshape(B, S, d), jnp.mean(aux)
     flat_mask = None if token_mask is None else token_mask.reshape(B * S)
-    out, aux = _moe_tokens(cfg, p, x.reshape(B * S, d), flat_mask)
+    out, aux = _moe_tokens(cfg, p, x.reshape(B * S, d), flat_mask,
+                           expert_capacity)
     return out.reshape(B, S, d), aux
 
 
-def _moe_tokens(cfg: ModelConfig, p: Params, xf, token_mask=None):
+def _moe_tokens(cfg: ModelConfig, p: Params, xf, token_mask=None,
+                expert_capacity: Optional[int] = None):
     """Capacity-based top-k dispatch over a flat token set xf: (N, d).
 
     ``token_mask``: optional (N,) bool — False rows (padding) are routed
     to a sentinel expert id E so they never occupy real expert capacity
     (a pad stealing a capacity slot would silently drop a REAL token and
     change its output — padded prefill must be exact).
+    ``expert_capacity`` overrides ``capacity(cfg, N)``.
     """
     N, d = xf.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    c = capacity(cfg, N)
+    c = capacity(cfg, N) if expert_capacity is None else expert_capacity
 
     logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
@@ -220,32 +226,51 @@ def moe_block_decode_paged(cfg: ModelConfig, p: Params, x, cache, pos,
     return x + m, new_cache
 
 
-def _extend_token_mask(x, valid_len):
-    """(B, S) mask of REAL extend rows: host-side padding must not
-    steal expert capacity from real tokens (same contract as padded
-    prefill).  The capacity BOUND still derives from the static
-    (B * S) shape — the usual carve-out; with capacity ample nothing
-    drops and extend == sequential decode."""
-    if valid_len is None:
-        return None
-    B, S, _ = x.shape
-    return jnp.arange(S, dtype=jnp.int32)[None, :] < valid_len[:, None]
+def _extend_token_mask(cfg: ModelConfig, x, valid_len, narrow=None):
+    """The REAL tokens of an extend wave, and the expert capacity they
+    get: (mask, capacity), either None where it does not apply.
+
+    Host-side padding must not steal expert capacity from real tokens
+    (same contract as padded prefill), so pads are False in the mask:
+    in the (B, S) form the rows ``i >= valid_len``, in the packed form
+    (``narrow``, x (1, n + R*S, d)) also the narrow rows with ``valid``
+    0.  The (B, S) form keeps the capacity its static B*S shape gives —
+    the usual carve-out; with capacity ample nothing drops and extend ==
+    sequential decode.  The packed wave of n slots and R wide rows of
+    width S gets ``min(n + R*S, capacity(cfg, n*S))``: the capacity the
+    (n, S) wave of the same slots had, bounded by the wave's token
+    count, as an expert takes at most one slot per token.  So where the
+    (n, S) wave dropped nothing (capacity at least its token count, as
+    at ``capacity_factor`` = experts / top-k), nothing drops here."""
+    if narrow is None:
+        if valid_len is None:
+            return None, None
+        S = x.shape[1]
+        mask = jnp.arange(S, dtype=jnp.int32)[None, :] < valid_len[:, None]
+        return mask, None
+    n, R = narrow.pos.shape[0], valid_len.shape[0]
+    S = (x.shape[1] - n) // R
+    wide = jnp.arange(S, dtype=jnp.int32)[None, :] < valid_len[:, None]
+    mask = jnp.concatenate([narrow.valid > 0, wide.reshape(-1)])[None]
+    return mask, min(x.shape[1], capacity(cfg, n * S))
 
 
 def moe_block_extend_paged(cfg: ModelConfig, p: Params, x, pos, cache,
                            block_tables, valid_len=None, *,
-                           use_pallas: bool = False):
+                           use_pallas: bool = False, narrow=None):
     """``moe_block_decode_paged`` for S tokens at once (speculative
-    verify / chunked catch-up)."""
+    verify / chunked catch-up; with ``narrow`` the packed two-group
+    wave, see ``transformer.extend_paged``)."""
     _, norm = L.make_norm(cfg)
     h = norm(p["ln1"], x)
     a, new_cache = L.attention_extend_paged(cfg, p["attn"], h, pos, cache,
                                             block_tables, valid_len,
-                                            use_pallas=use_pallas)
+                                            use_pallas=use_pallas,
+                                            narrow=narrow)
     x = x + a
     h = norm(p["ln2"], x)
-    m, _ = moe_mlp(cfg, p["moe"], h,
-                   token_mask=_extend_token_mask(x, valid_len))
+    mask, c = _extend_token_mask(cfg, x, valid_len, narrow)
+    m, _ = moe_mlp(cfg, p["moe"], h, token_mask=mask, expert_capacity=c)
     return x + m, new_cache
 
 
@@ -371,18 +396,20 @@ def decode_step_paged(cfg: ModelConfig, params: Params, cache: Params,
 
 def extend_paged(cfg: ModelConfig, params: Params, cache: Params, tokens,
                  pos, block_tables, valid_len=None,
-                 use_pallas: bool = False):
-    """Score S tokens against the paged cache in one call (all MoE
-    attention is global => fully paged).  See ``transformer.extend_paged``
-    for the row semantics and the ``valid_len`` write-drop contract."""
-    x = L.embed(cfg, params["embed"], tokens)
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (x.shape[0],))
+                 use_pallas: bool = False, narrow=None):
+    """Score S tokens per row against the paged cache in one call (all
+    MoE attention is global => fully paged).  See
+    ``transformer.extend_paged`` for the (R, S) and packed layouts, the
+    row semantics and the ``valid_len`` write-drop contract."""
+    x = L.embed(cfg, params["embed"], L.wave_tokens(tokens, narrow))
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (tokens.shape[0],))
     new_cache = {}
     if cfg.first_dense_layers:
         def dbody(h, inp):
             lp, cc = inp
             h, c2 = T.block_extend_paged(cfg, lp, h, pos, cc, block_tables,
-                                         valid_len, use_pallas=use_pallas)
+                                         valid_len, use_pallas=use_pallas,
+                                         narrow=narrow)
             return h, c2
         x, dc = lax.scan(dbody, x, (params["dense_layers"],
                                     cache["dense_layers"]))
@@ -391,7 +418,8 @@ def extend_paged(cfg: ModelConfig, params: Params, cache: Params, tokens,
     def body(h, inp):
         lp, cc = inp
         h, c2 = moe_block_extend_paged(cfg, lp, h, pos, cc, block_tables,
-                                       valid_len, use_pallas=use_pallas)
+                                       valid_len, use_pallas=use_pallas,
+                                       narrow=narrow)
         return h, c2
     x, mc = lax.scan(body, x, (params["moe_layers"], cache["moe_layers"]))
     new_cache["moe_layers"] = mc
@@ -399,6 +427,8 @@ def extend_paged(cfg: ModelConfig, params: Params, cache: Params, tokens,
     _, norm = L.make_norm(cfg)
     x = norm(params["final_norm"], x)
     logits = L.unembed(cfg, params["embed"], params["unembed"], x)
+    if narrow is not None:
+        logits = L.split_wave(logits, narrow, tokens.shape[0])
     return logits, new_cache
 
 
@@ -428,7 +458,7 @@ def extend(cfg: ModelConfig, params: Params, cache: Params, tokens, pos,
         h = h + a
         hh = norm(lp["ln2"], h)
         m, _ = moe_mlp(cfg, lp["moe"], hh,
-                       token_mask=_extend_token_mask(h, valid_len))
+                       token_mask=_extend_token_mask(cfg, h, valid_len)[0])
         return h + m, c2
     x, mc = lax.scan(body, x, (params["moe_layers"], cache["moe_layers"]))
     new_cache["moe_layers"] = mc

@@ -139,14 +139,16 @@ def block_decode_paged(cfg: ModelConfig, p: Params, x, cache, pos,
 
 def block_extend_paged(cfg: ModelConfig, p: Params, x, pos, cache,
                        block_tables, valid_len=None, *,
-                       use_pallas: bool = False):
+                       use_pallas: bool = False, narrow=None):
     """``block_decode_paged`` for S tokens at once — speculative verify
-    / chunked catch-up (``layers.attention_extend_paged``)."""
+    / chunked catch-up (``layers.attention_extend_paged``; with
+    ``narrow`` the packed two-group wave, x flattened)."""
     _, norm = L.make_norm(cfg)
     h = norm(p["ln1"], x)
     a, new_cache = L.attention_extend_paged(cfg, p["attn"], h, pos, cache,
                                             block_tables, valid_len,
-                                            use_pallas=use_pallas)
+                                            use_pallas=use_pallas,
+                                            narrow=narrow)
     if cfg.sandwich_norms:
         a = norm(p["ln1_post"], a)
     x = x + a
@@ -407,34 +409,50 @@ def decode_step_paged(cfg: ModelConfig, params: Params, cache: Params,
 
 def extend_paged(cfg: ModelConfig, params: Params, cache: Params, tokens,
                  pos, block_tables, valid_len=None,
-                 use_pallas: bool = False):
-    """Score S tokens against the paged cache in ONE jitted call.
+                 use_pallas: bool = False, narrow=None):
+    """Score S tokens per row against the paged cache in ONE jitted call.
 
-    tokens: (B, S) int32 at absolute positions ``pos + i`` (pos: (B,)
-    int32 per-slot write frontier).  Global layers extend through their
-    page pool (``layers.attention_extend_paged``); local ring layers
-    (gemma patterns) extend their dense window with the same pre-write
-    causal-suffix semantics (``layers.attention_extend``; requires
-    S <= local_window).  Returns (logits (B, S, V), new_cache) — row i
-    is the next-token distribution AFTER consuming ``tokens[:, :i+1]``,
-    which is what speculative verify and multi-token catch-up prefill
-    consume.  Rows ``i >= valid_len`` are padding: their logits are
-    garbage and their K/V writes are dropped.
+    Two layouts:
+
+    * **(R, S)** (``narrow=None``): tokens (R, S) int32 at absolute
+      positions ``pos + i`` (pos: (R,) int32 per-row write frontier).
+      Global layers extend through their page pool
+      (``layers.attention_extend_paged``); local ring layers (gemma
+      patterns) extend their dense window with the same pre-write
+      causal-suffix semantics (``layers.attention_extend``; requires
+      S <= local_window).  Returns (logits (R, S, V), new_cache) — row
+      i is the next-token distribution AFTER consuming
+      ``tokens[:, :i+1]``, which is what speculative verify and
+      multi-token catch-up prefill consume.  Rows ``i >= valid_len`` are
+      padding: their logits are garbage and their K/V writes are
+      dropped.
+    * **packed** (``narrow``, a ``layers.NarrowRows``; uniform trunks
+      only, ``pattern_period <= 1``): ``tokens``, ``pos``,
+      ``block_tables`` and ``valid_len`` describe R wide rows as above
+      (a row with ``valid_len`` 0 and a -1 table is padding), and
+      ``narrow`` adds n width-1 rows read as a decode wave reads them.
+      Every token-wise layer runs once over the n + R*S tokens.  Returns
+      ((narrow logits (n, 1, V), wide logits (R, S, V)), new_cache).
     """
-    x = L.embed(cfg, params["embed"], tokens)
+    x = L.embed(cfg, params["embed"], L.wave_tokens(tokens, narrow))
     trunk = params["trunk"]
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (x.shape[0],))
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (tokens.shape[0],))
 
     if cfg.pattern_period <= 1:
         def body(h, inp):
             lp, c = inp
             h, c2 = block_extend_paged(cfg, lp, h, pos, c, block_tables,
-                                       valid_len, use_pallas=use_pallas)
+                                       valid_len, use_pallas=use_pallas,
+                                       narrow=narrow)
             return h, c2
         x, new_c = lax.scan(body, x, (_uniform_layers(cfg, trunk),
                                       cache["layers"]))
         new_cache = {"layers": new_c}
     else:
+        if narrow is not None:
+            raise ValueError("a packed extend wave needs a uniform paged "
+                             "trunk (pattern_period <= 1)")
+
         def local_body(h, inp):
             lp, c = inp
             h, c2 = block_extend(cfg, lp, h, c, pos, is_global=False,
@@ -460,6 +478,8 @@ def extend_paged(cfg: ModelConfig, params: Params, cache: Params, tokens,
     _, norm = L.make_norm(cfg)
     x = norm(params["final_norm"], x)
     logits = L.unembed(cfg, params["embed"], params["unembed"], x)
+    if narrow is not None:
+        logits = L.split_wave(logits, narrow, tokens.shape[0])
     return logits, new_cache
 
 

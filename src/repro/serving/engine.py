@@ -217,6 +217,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
+from repro.models.layers import NarrowRows
 from repro.serving.kv_pool import KVBlockPool, PoolExhausted, \
     blocks_for_tokens
 from repro.serving.prefix_cache import (PrefixStoreError, RadixPrefixCache,
@@ -533,6 +534,16 @@ class EdgeServingEngine:
                               and self.K >= 2
                               and (cfg.pattern_period <= 1
                                    or self.K <= W))
+        # packed extend waves: width-1 slots ride a decode-shaped row
+        # group and wider spans a bucket of R wide rows (R the smallest
+        # of the powers of two up to max_slots, and max_slots, that
+        # holds them), on the paged engine of families whose
+        # extend_paged takes both groups; the rest keep (B, K) waves
+        self.packed = bool(self.extend_ok and self.paged
+                           and M.extend_packable(cfg))
+        self._wide_buckets = tuple(sorted(
+            {1 << i for i in range(B.bit_length())} | {B}))
+        self._extend_compiled: dict[tuple, Callable] = {}
         # Sarathi-style admission: prompts become wave spans (pending
         # catch-up from position 0 / the prefix-hit frontier) instead
         # of a blocking bucketed prefill.  Recurrent families with no
@@ -603,8 +614,8 @@ class EdgeServingEngine:
         # the fork rewrites one page in place, not a second pool copy)
         self._copy_page = (jax.jit(self._copy_page_fn, donate_argnums=(0,))
                            if self.paged else None)
-        # multi-token extend wave (width spec_gamma static; at most two
-        # variants compile — with and without the full-logits return)
+        # multi-token extend wave (width K static; with and without the
+        # full-logits return, and per wide-row bucket when packed)
         self._extend = (jax.jit(self._extend_fn, donate_argnums=(1,),
                                 static_argnames=("need_logits",))
                         if self.extend_ok else None)
@@ -1213,16 +1224,25 @@ class EdgeServingEngine:
         return nxt.astype(jnp.int32), new_cache
 
     def _extend_fn(self, params, cache, tokens, pos, valid, block_tables,
-                   need_logits: bool = False):
-        """Multi-token wave: score ``spec_gamma`` tokens per slot in one
-        call (speculative verify / chunked catch-up).  Acceptance and
-        sampling are host-side with the engine rng — which keeps greedy
-        spec bit-identical to vanilla (argmax is rounding-free) and
-        rejection sampling deterministic per seed — but an all-greedy
-        wave ships only the (B, K) per-row argmax ids; the full
-        (B, K, V) float32 logits cross the device boundary only when
+                   narrow=None, need_logits: bool = False):
+        """Multi-token wave: score up to K tokens per row in one call
+        (speculative verify / chunked catch-up).  ``tokens`` (R, K),
+        ``pos``, ``valid`` and ``block_tables`` are the wave's rows —
+        every slot's (B, K) block, or, with ``narrow``
+        (``layers.NarrowRows``), only the R wide rows beside the
+        slots' width-1 row group (``model.extend_paged``'s packed
+        layout).  Acceptance and sampling are host-side with the engine
+        rng — which keeps greedy spec bit-identical to vanilla (argmax
+        is rounding-free) and rejection sampling deterministic per
+        seed — but an all-greedy wave ships only the per-row argmax
+        ids; the float32 logits cross the device boundary only when
         some active slot samples at temperature > 0 (at real vocab
-        sizes that transfer dominates the wave)."""
+        sizes that transfer dominates the wave).  Outputs are arrays,
+        or (narrow, wide) pairs when packed.
+
+        The benchmark's trace reader (``bench/trace.py``) finds this
+        wave's device time by the name ``_extend_fn`` in the jitted
+        module's name: keep the name."""
         if block_tables is None:
             logits, new_cache = M.extend(self.cfg, params, cache, tokens,
                                          pos, valid)
@@ -1230,10 +1250,29 @@ class EdgeServingEngine:
             logits, new_cache = M.extend_paged(self.cfg, params, cache,
                                                tokens, pos, block_tables,
                                                valid,
-                                               self.scfg.use_pallas_paged)
-        logits = logits.astype(jnp.float32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                                               self.scfg.use_pallas_paged,
+                                               narrow=narrow)
+        logits = jax.tree.map(lambda a: a.astype(jnp.float32), logits)
+        greedy = jax.tree.map(
+            lambda a: jnp.argmax(a, axis=-1).astype(jnp.int32), logits)
         return greedy, (logits if need_logits else None), new_cache
+
+    def _run_extend(self, args: tuple, need_logits: bool):
+        """Dispatch one extend wave.  A packed engine compiles every
+        wide-row bucket ahead of time at its first wave (per
+        ``need_logits``), so no later wave compiles: a server's warm-up
+        then carries every bucket's compile."""
+        if not self.packed:
+            return self._extend(*args, need_logits=need_logits)
+        key = (args[2].shape[0], need_logits)
+        if key not in self._extend_compiled:
+            for r in self._wide_buckets:
+                wide = [jax.ShapeDtypeStruct((r,) + a.shape[1:], a.dtype)
+                        for a in args[2:6]]
+                self._extend_compiled[(r, need_logits)] = self._extend.lower(
+                    *args[:2], *wide, *args[6:],
+                    need_logits=need_logits).compile()
+        return self._extend_compiled[key](*args)
 
     def _ensure_blocks(self, spans: Optional[dict] = None) -> None:
         """Guarantee every active slot's table covers its write span
@@ -1534,11 +1573,18 @@ class EdgeServingEngine:
             if self.paged:
                 self.peak_pool_used = max(self.peak_pool_used,
                                           self.pool.num_used)
+        # the wave's rows: packed, each slot's width-1 row plus R wide
+        # rows for the slots fed more than one token; else (B, K)
+        wide = ([s for s, (_, v) in plan.items() if v > 1] if self.packed
+                else list(range(B)))
+        R = next(b for b in self._wide_buckets if b >= len(wide))
         # positions the wave computes, and those that carry a token
         # (model.extend_pad_share reads the padding off these two)
         n_fed = sum(v for _, v in plan.values())
-        self.metrics.counter("engine.extend_positions").inc(B * K)
+        self.metrics.counter("engine.extend_positions").inc(
+            B + R * K if self.packed else B * K)
         self.metrics.counter("engine.extend_fed").inc(n_fed)
+        self.metrics.counter("engine.extend_rows").inc(R)
 
         spec_slots = [s for s, (m, _) in plan.items() if m == "spec"]
         proposals, dists = {}, {}
@@ -1552,35 +1598,70 @@ class EdgeServingEngine:
                     spec_slots, self.tokens[:, 0], self.temps,
                     self.topks, k_spec, self._rng)
 
-        # all-greedy waves ship only the (B, K) argmax ids
+        # all-greedy waves ship only the argmax ids
         need_logits = bool((self.temps[self.active] > 0).any())
         with self._span("dispatch", mode="extend", rows=n_active,
-                        fed_tokens=n_fed):
-            fed = np.zeros((B, K), np.int32)
-            valid = np.ones((B,), np.int32)
-            for s, (mode, v) in plan.items():
+                        fed_tokens=n_fed, rows_wide=R):
+            fed = np.zeros((R, K), np.int32)
+            valid = (np.zeros((R,), np.int32) if self.packed
+                     else np.ones((R,), np.int32))
+            for r, s in enumerate(wide):
+                if s not in plan:
+                    continue
+                mode, v = plan[s]
                 seq = [int(self.tokens[s, 0])]
                 if mode == "catch":
                     seq += [int(t) for t in self.pending[s][:v - 1]]
                 elif mode == "spec":
                     seq += proposals[s][:v - 1]
-                fed[s, :len(seq)] = seq
-                fed[s, len(seq):] = seq[-1]       # pad (write-dropped)
-                valid[s] = v
-            tables = jnp.asarray(self.block_tables) if self.paged else None
-            greedy, logits, self.cache = self._extend(
-                self.params, self.cache, jnp.asarray(fed),
-                jnp.asarray(self.pos), jnp.asarray(valid), tables,
-                need_logits=need_logits)
+                fed[r, :len(seq)] = seq
+                fed[r, len(seq):] = seq[-1]       # pad (write-dropped)
+                valid[r] = v
+            narrow = None
+            if self.packed:
+                # a wide slot's narrow row and an idle slot's feed no
+                # token: valid 0 drops their writes; pad wide rows
+                # carry no table
+                tables = np.full((R, self.n_blk), -1, np.int32)
+                tables[:len(wide)] = self.block_tables[wide]
+                pos = np.zeros((R,), np.int32)
+                pos[:len(wide)] = self.pos[wide]
+                narrow = NarrowRows(
+                    jnp.asarray(self.tokens), jnp.asarray(self.pos),
+                    jnp.asarray(self.block_tables),
+                    jnp.asarray(np.array(
+                        [s in plan and plan[s][1] == 1 for s in range(B)],
+                        np.int32)))
+                tables = jnp.asarray(tables)
+            else:
+                pos = self.pos
+                tables = (jnp.asarray(self.block_tables) if self.paged
+                          else None)
+            greedy, logits, self.cache = self._run_extend(
+                (self.params, self.cache, jnp.asarray(fed),
+                 jnp.asarray(pos), jnp.asarray(valid), tables, narrow),
+                need_logits)
         with self._span("sync"):
-            greedy = np.asarray(greedy)                  # (B, K)
-            logits = (np.asarray(logits, np.float32) if need_logits
-                      else None)                         # (B, K, V)
+            greedy = jax.tree.map(np.asarray, greedy)
+            if need_logits:
+                logits = jax.tree.map(
+                    lambda a: np.asarray(a, np.float32), logits)
+        # each slot's outputs: its wide row, or its narrow row (width 1)
+        row = {s: r for r, s in enumerate(wide)}
+        if self.packed:
+            def rows_of(out, s):
+                return out[1][row[s]] if s in row else out[0][s]
+        else:
+            def rows_of(out, s):
+                return out[s]
+        greedy = {s: rows_of(greedy, s) for s in plan}
+        if need_logits:
+            logits = {s: rows_of(logits, s) for s in plan}
 
-        def sample(s, row, temp, top_k):
+        def sample(s, i, temp, top_k):
             if temp <= 0:
-                return int(greedy[s, row])
-            return sample_from_logits(logits[s, row], temp, top_k,
+                return int(greedy[s][i])
+            return sample_from_logits(logits[s][i], temp, top_k,
                                       self._rng)
 
         any_spec = False
@@ -1630,11 +1711,11 @@ class EdgeServingEngine:
                 any_spec = True
                 if temp <= 0:
                     n_acc, emitted = accept_greedy(proposals[s][:v - 1],
-                                                   greedy[s, :v])
+                                                   greedy[s][:v])
                 else:
                     n_acc, emitted = accept_proposals(
                         proposals[s][:v - 1], dists[s][:v - 1],
-                        logits[s, :v], temp, top_k, self._rng)
+                        logits[s][:v], temp, top_k, self._rng)
                 self.spec.advance(s, n_acc + 1)
                 self.spec_rounds += 1
                 self.spec_proposed += v - 1

@@ -378,17 +378,22 @@ def _chunked_requests():
 
 
 def test_extend_wave_counters_exact(model):
-    """4 slots x K=8 positions per extend wave.  The 20-token prompt
-    catches up 8, 8, 4 tokens; the 5-token prompt feeds 5, then rides
-    along at width 1 while the other still catches up.  Three extend
-    waves, then decode waves (which count nothing)."""
+    """4 slots, K=8, packed waves: each extend wave computes one
+    width-1 row per slot (4 positions) plus R wide rows of 8, R the
+    bucket holding the slots fed more than one token.  The 20-token
+    prompt catches up 8, 8, 4 tokens; the 5-token prompt feeds 5 (a
+    wide row beside the first: R = 2), then rides along at width 1 in
+    the narrow group while the other still catches up (R = 1).  Three
+    extend waves, then decode waves (which count nothing)."""
     _, eng = _chunked_engine(model)
+    assert eng.packed
     for r in _chunked_requests():
         eng.submit(r)
     eng.run_until_drained()
     snap = eng.metrics.collect()
-    assert snap["engine.extend_positions"] == 3 * 4 * 8
+    assert snap["engine.extend_positions"] == (4 + 2 * 8) + (4 + 8) + (4 + 8)
     assert snap["engine.extend_fed"] == (8 + 5) + (8 + 1) + (4 + 1)
+    assert snap["engine.extend_rows"] == 2 + 1 + 1
     assert not any(k.startswith("extend") for k in eng.stats())
 
 
