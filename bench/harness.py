@@ -125,17 +125,14 @@ class StepLog:
                 fed[uid] = fed.get(uid, 0) + int(a.get("n", 1))
             elif kind == "tokens":
                 emitted[uid] = emitted.get(uid, 0) + int(a.get("n", 1))
-        conf, L = self.conf, self.conf["num_hidden_layers"]
         rows, total = [], 0
         for uid in sorted(set(fed) | set(emitted)):
             n = fed.get(uid, 1)          # a decoding slot feeds one token
             p0 = self.pos.get(uid, 0)
             self.pos[uid] = p0 + n
             rows.append((p0, n))
-            H, hd = conf["num_attention_heads"], spec.head_dim(conf)
-            total += (L * n * flops.layer_matmul_flops(conf)
-                      + L * 4 * H * hd * (n * p0 + n * (n + 1) // 2)
-                      + flops.head_flops(conf) * min(emitted.get(uid, 0), 1))
+            total += flops.token_flops(self.conf, p0, n,
+                                       min(emitted.get(uid, 0), 1))
         return {"kind": "extend" if fed else "decode", "flops": total,
                 "ctxs": [p0 + 1 for p0, n in rows] if not fed else []}
 
@@ -301,20 +298,18 @@ def logit_gaps(conf: dict, seed: int, reqs, control: bool = False) -> dict:
     first at each of those positions."""
     import jax.numpy as jnp
     from bench import reference
-    w = weights.canonical(conf, seed)
-    models = {"": reference.Model(conf)}
-    if control:
-        models["control_"] = reference.Model(conf, "fp8")
-    gaps = {k: [] for k in models}
-    for r in reqs:
-        P, m = len(r.prompt), len(r.tokens)
-        seq = np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
-        rows = np.arange(P - 1, P + m - 1)
-        ref = models[""].logits(w, seq, rows)
+    seqs = [np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
+            for r in reqs]
+    rows = [np.arange(len(r.prompt) - 1, len(r.prompt) + len(r.tokens) - 1)
+            for r in reqs]
+    modes = ("f32", "fp8") if control else ("f32",)
+    gaps = {"": [], "control_": []} if control else {"": []}
+    for r, out in zip(reqs, reference.logits(conf, seed, seqs, rows, modes)):
+        ref = out["f32"]
         best = ref.max(-1)
-        for k, model in models.items():
+        for k in gaps:
             pick = (jnp.asarray(r.tokens, jnp.int32) if not k
-                    else model.logits(w, seq, rows).argmax(-1))
+                    else out["fp8"].argmax(-1))
             got = jnp.take_along_axis(ref, pick[:, None], 1)[:, 0]
             gaps[k].append(np.asarray(best - got, np.float64))
     out = {"tokens": sum(len(r.tokens) for r in reqs),

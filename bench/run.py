@@ -98,7 +98,8 @@ def result(bench: dict, cell: dict, out: dict, peak: dict,
         values = {m["name"]: readers[m["name"]](rec) for m in wanted}
     else:
         wanted = spec.cell_metrics(bench, cell["name"], "end_to_end")
-        values = {m["name"]: e2e.get(m["name"]) for m in wanted}
+        values = {m["name"]: e2e.get(spec.quantity(m["name"]))
+                  for m in wanted}
     metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                for m in wanted if values[m["name"]] is not None}
     chk = out["check"]

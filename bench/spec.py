@@ -1,11 +1,12 @@
 """Find a cell's files by name and turn them into the program's configs.
 
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; the
-configuration lives in ``bench/configs/<config>.json``, the mix in
+configuration lives in ``bench/configs/<config>.json``, its layers in
+``bench/arch/<model_type>.py``, the mix in
 ``bench/traffic/<traffic>.json`` and each per-layer metric's reader in
-``bench/metrics/<metric>.py``.  Nothing here knows a cell by name, so a
-later change adds a cell, a configuration, a mix or a metric by adding
-files and entries only.
+``bench/metrics/<metric>.py``.  Nothing here knows a cell or an
+architecture by name, so a later change adds a cell, a configuration, an
+architecture, a mix or a metric by adding files and entries only.
 """
 from __future__ import annotations
 
@@ -14,23 +15,10 @@ import importlib.util
 import json
 from pathlib import Path
 
+from bench import arch
+
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
-
-# Published (Hugging Face ``config.json``) keys -> ``ModelConfig`` fields.
-HF_TO_PROGRAM = {
-    "hidden_size": "d_model",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "num_local_experts": "num_experts",
-    "num_experts_per_tok": "num_experts_per_tok",
-    "attention_multiplier": "attn_scale",
-}
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -55,31 +43,16 @@ def load_traffic(name: str) -> dict:
     return json.loads((BENCH_DIR / "traffic" / f"{name}.json").read_text())
 
 
-def head_dim(conf: dict) -> int:
-    return conf.get("head_dim") or (conf["hidden_size"]
-                                    // conf["num_attention_heads"])
-
-
-def ffn_width(conf: dict) -> int:
-    return conf["intermediate_size"]
-
-
-def is_moe(conf: dict) -> bool:
-    return bool(conf.get("num_local_experts"))
-
-
 def model_config(conf: dict):
     """The program's ``ModelConfig`` for a configuration file: the
     registry entry named by ``registry_id``, with every published size
-    of the file written over it and then the file's ``program`` fields
+    of the file written over it (by the architecture's
+    ``program_fields``) and then the file's ``program`` fields
     (settings of the program with no published key)."""
     from repro.configs import get_config
     from repro.configs.base import ModelConfig
 
-    kw = {field: conf[key] for key, field in HF_TO_PROGRAM.items()
-          if key in conf}
-    kw["head_dim"] = head_dim(conf)
-    kw["moe_d_ff" if is_moe(conf) else "d_ff"] = ffn_width(conf)
+    kw = arch.module(conf).program_fields(conf)
     kw.update(conf.get("program", {}))
     known = {f.name for f in dataclasses.fields(ModelConfig)}
     unknown = sorted(set(kw) - known)
@@ -116,6 +89,14 @@ def metric_readers(names) -> dict:
         spec.loader.exec_module(mod)
         out[name] = mod.read
     return out
+
+
+def quantity(name: str) -> str:
+    """What an end-to-end metric reads: ``itl_p95_ms.cold`` is the
+    harness's ``itl_p95_ms`` in the cells it lists, under a bound of its
+    own, so cells that spread differently can hold one quantity to
+    different bounds."""
+    return name.split(".", 1)[0]
 
 
 def cell_metrics(bench: dict, cell: str, section: str) -> list[dict]:

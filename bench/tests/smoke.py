@@ -1,23 +1,23 @@
 """Smoke-size copies of the benchmark's configurations and mixes, for
-running the harness on the CPU in tests: every width shrunk, the same
-code paths (paged pool, prefix cache, chunked prefill, Pallas decode
-kernel in interpret mode)."""
+running the harness on the CPU in tests: every width shrunk (by each
+architecture's ``smoke``, ``bench/arch``), the same code paths (paged
+pool, prefix cache, chunked prefill, Pallas decode kernel in interpret
+mode)."""
 from __future__ import annotations
 
 import copy
 
-from bench import spec
-
-SMALL = dict(hidden_size=128, num_attention_heads=4, num_key_value_heads=2,
-             vocab_size=512, num_hidden_layers=2, intermediate_size=64)
+from bench import arch, spec
 
 
 def conf(name: str) -> dict:
-    c = copy.deepcopy(spec.load_config(name))
-    c.update(SMALL)
-    if spec.is_moe(c):
-        c.update(num_local_experts=4, num_experts_per_tok=2)
-        c["program"] = dict(c.get("program", {}), capacity_factor=2.0)
+    return shrink(spec.load_config(name))
+
+
+def shrink(conf: dict) -> dict:
+    """The architecture's own CPU shrink (``smoke`` of its module), with
+    a pool of 4 slots of 256 positions and 16-token chunks."""
+    c = arch.module(conf).smoke(conf)
     c["serve"].update(max_slots=4, max_len=256, catch_chunk=16)
     return c
 

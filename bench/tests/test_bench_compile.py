@@ -71,9 +71,8 @@ def test_wave_compiles_and_fits(name, wave, one_chip, compiled_kernels):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(
-        lambda k: weights._to_program(conf, weights._canonical(conf, k)),
-        jax.random.PRNGKey(0)))
+    params = on_chip(jax.eval_shape(weights.program_maker(conf, mcfg),
+                                    jax.random.PRNGKey(0)))
     cache = on_chip(jax.eval_shape(partial(M.init_paged_cache, mcfg, B, T,
                                            B * n_blk, bs)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
